@@ -20,8 +20,8 @@ sit outside the package and drive internals deliberately):
   module to import ANY kernel;
 * any ``from ...epoch_fast import <name>`` or ``epoch_fast.<name>``
   access whose name is not the wrapper's public surface
-  (``ENABLED`` / ``SWEEP_SITE`` / ``scalar_epoch`` / ``fused_epoch`` /
-  ``set_guard``).
+  (``ENABLED`` / ``SWEEP_SITE`` / ``scalar_epoch`` / ``numpy_epoch`` /
+  ``fused_epoch`` / ``set_guard``).
 
 A deliberate exception carries
 ``# speclint: disable=epoch-scalar-bypass -- <reason>``.
@@ -37,7 +37,8 @@ _DEVICE = "consensus_specs_tpu.ops.epoch_sweep"
 
 # the wrapper's whole public surface; everything else is engine-internal
 _ALLOWED = frozenset({
-    "ENABLED", "SWEEP_SITE", "scalar_epoch", "fused_epoch", "set_guard",
+    "ENABLED", "SWEEP_SITE", "scalar_epoch", "numpy_epoch", "fused_epoch",
+    "set_guard",
 })
 
 
@@ -69,7 +70,8 @@ def _surface_finding(sf: SourceFile, node: ast.AST, name: str) -> Finding:
         f"epoch_fast.{name} is engine-internal — epoch array math "
         f"outside the seam runs unsupervised and uncounted",
         hint="use the public surface (ENABLED, SWEEP_SITE, "
-             "scalar_epoch, fused_epoch, set_guard) or carry a "
+             "scalar_epoch, numpy_epoch, fused_epoch, set_guard) or "
+             "carry a "
              "reasoned disable")
 
 

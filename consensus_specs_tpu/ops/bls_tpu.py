@@ -105,6 +105,20 @@ def _next_pow2(n: int) -> int:
     return 1 << (n - 1).bit_length() if n > 1 else 1
 
 
+# On an accelerator the pairs axis pads to at least one mainnet block
+# flush (128 attestations + sync aggregate + proposer + RANDAO, 132
+# pairs folded): an invalid block's bisection probes (66, 34, ... pairs)
+# then reuse the flush's compiled stage kernels instead of compiling
+# ~50 s of TPU programs per power-of-two size.  Padded pairs are skip
+# lanes; on CPU hosts they are real serial work, so the floor is 1.
+_DEVICE_MIN_PAIRS = 256
+
+
+def _min_pairs() -> int:
+    import jax
+    return 1 if jax.default_backend() == "cpu" else _DEVICE_MIN_PAIRS
+
+
 def _run_pairing_checks(jobs):
     """jobs: list of lists of (G1 Point, G2 Point) pairs.  Returns
     np.bool_ verdicts, one per job.
@@ -117,7 +131,7 @@ def _run_pairing_checks(jobs):
     if not jobs:
         return np.zeros(0, dtype=bool)
     n_real = len(jobs)
-    k = _next_pow2(max(len(j) for j in jobs))
+    k = max(_next_pow2(max(len(j) for j in jobs)), _min_pairs())
     jobs = list(jobs) + [[]] * (_next_pow2(n_real) - n_real)
     xs1, ys1, xs2, ys2, skips = [], [], [], [], []
     for job in jobs:

@@ -13,6 +13,7 @@ Oracle: crypto/curve.py (same formulas on Python ints).
 """
 from __future__ import annotations
 
+import functools
 from types import SimpleNamespace
 
 import numpy as np
@@ -156,10 +157,24 @@ def point_sum_tree(F, pts):
     return (X[0], Y[0], Z[0])
 
 
+def point_sum_seq(F, pts):
+    """Reduce points stacked on axis 0 ([n, ...]) with one lax.scan over
+    n: a single point_add in the program at any n.  A host-driven
+    halving tree instead compiles one add kernel per halving shape —
+    ~20-40 s each for the TPU, eight or nine per reduction."""
+    acc = point_infinity_like(F, tuple(c[0] for c in pts))
+
+    def step(acc, p):
+        return point_add(F, acc, p), None
+
+    acc, _ = jax.lax.scan(step, acc, pts)
+    return acc
+
+
 # NOTE: no fused msm() here on purpose — jitting scalar-mul + the full
 # unrolled reduction tree in one graph is what pushed the 4096-point
 # MSM compile past the bench budget.  ops/msm.py composes
-# g*_scalar_mul with a host-driven pairwise tree over g*_add instead.
+# g*_scalar_mul with the scanned sum below instead.
 
 # ---------------------------------------------------------------------------
 # jitted entry points (compile once per shape; eager dispatch of the limb
@@ -173,6 +188,18 @@ g1_sum = jax.jit(lambda p: point_sum_tree(F1, p))
 g2_add = jax.jit(lambda p, q: point_add(F2, p, q))
 g2_double = jax.jit(lambda p: point_double(F2, p))
 g2_scalar_mul = jax.jit(lambda p, bits: point_scalar_mul(F2, p, bits))
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def g1_sum_axis(p, axis):
+    """Sum G1 Jacobian limb tensors along `axis` (one scanned add)."""
+    return point_sum_seq(F1, tuple(jnp.moveaxis(c, axis, 0) for c in p))
+
+
+@functools.partial(jax.jit, static_argnums=1)
+def g2_sum_axis(p, axis):
+    """Sum G2 Jacobian limb tensors along `axis` (one scanned add)."""
+    return point_sum_seq(F2, tuple(jnp.moveaxis(c, axis, 0) for c in p))
 
 
 # ---------------------------------------------------------------------------

@@ -239,7 +239,6 @@ def run_sweep(inp):
     altair → (balances, scores, new_eff, elig_q, eject, ready)."""
     import jax
 
-    from ..parallel.mesh import enable_x64
     from ..parallel.shard_verify import mesh_devices, shard_jobs
 
     phase0 = inp.family == "phase0"
@@ -258,7 +257,7 @@ def run_sweep(inp):
     scalars = [inp.scalars[k] for k in scalar_order]
     # build/trace under x64 too: the program closes over uint64
     # constants (FAR epochs) that overflow 32-bit lanes
-    with enable_x64():
+    with jax.enable_x64(True):
         fn = _program(inp.family, inp.statics)
         arrays = shard_jobs(tuple(arrays), SITE)
         out = jax.device_get(fn(*arrays, *scalars))

@@ -35,6 +35,7 @@ MASK = BASE - 1
 
 R_MONT = pow(2, LIMB_BITS * LIMBS, Q)          # Montgomery radix R mod q
 R2_MONT = R_MONT * R_MONT % Q                  # R^2 mod q (to-Mont factor)
+R_INV_MONT = pow(R_MONT, -1, Q)                # R^-1 mod q (from-Mont)
 NINV = (-pow(Q, -1, BASE)) % BASE              # -q^{-1} mod 2^12
 
 
@@ -311,9 +312,9 @@ def pack_mont(xs) -> jnp.ndarray:
 
 
 def unpack_mont(v) -> list:
-    arr = np.asarray(from_mont_np(v))
-    return [from_limbs(arr[i]) for i in range(arr.shape[0])]
-
-
-def from_mont_np(v):
-    return np.asarray(from_mont(jnp.asarray(v)))
+    """Montgomery limb rows -> canonical ints, decoded on the host: one
+    bigint multiply by R^-1 per element.  (Decoding through eager
+    device `from_mont` dispatched dozens of primitive ops per call —
+    over a minute per block flush on the chip.)"""
+    arr = np.asarray(v)
+    return [from_limbs(arr[i]) * R_INV_MONT % Q for i in range(arr.shape[0])]

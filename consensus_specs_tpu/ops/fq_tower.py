@@ -384,11 +384,8 @@ def fq2_pack_mont(vals) -> jnp.ndarray:
 def fq2_unpack_mont(arr):
     from ..crypto.fields import Fq2
     a = np.asarray(arr)
-    out = []
-    for i in range(a.shape[0]):
-        c = fq.unpack_mont(a[i])
-        out.append(Fq2(c[0], c[1]))
-    return out
+    c = fq.unpack_mont(a.reshape(-1, fq.LIMBS))
+    return [Fq2(c[2 * i], c[2 * i + 1]) for i in range(a.shape[0])]
 
 
 def fq6_pack_mont(vals) -> jnp.ndarray:

@@ -6,10 +6,10 @@ aggregate — and a flush carries many sets.  `g1_add_sweep` fuses ALL of
 them into one padded ragged-segment tree reduction: the lists are packed
 into a single [segments, length] Jacobian limb tensor (infinity-padded,
 both axes rounded to powers of two so XLA only ever sees log-many
-shapes), then reduced along the length axis with log2(L) batched
-`point_add` launches at halving shapes — the same host-driven halving
-discipline as ops/msm.py's `_tree_sum_host`, reusing ops/curve_jax.py's
-complete Jacobian arithmetic unchanged.
+shapes), then reduced along the length axis by one scanned `point_add`
+(ops/curve_jax.py `g1_sum_axis`, the complete Jacobian arithmetic
+unchanged) — one compiled program per shape, where a host-driven
+halving tree compiled one add kernel per halving shape.
 
 Engine selection (G1_SWEEP_MODE env: "jax" | "oracle") is the same
 platform split as msm.MSM_MODE / pairing_jax.PAIRING_MODE: the limb
@@ -108,16 +108,12 @@ def _jax_sweep(point_lists):
     # cross-device traffic; a 1-device mesh is a no-op
     from ..parallel import shard_verify
     X, Y, Z = shard_verify.shard_jobs((X, Y, Z), "ops.g1_aggregate")
-    # halving tree along the segment-length axis: log2(L) launches of
-    # the one jitted pairwise-add kernel at power-of-two shapes (the
-    # fully unrolled tree is the compile blow-up msm.py already avoids)
-    while X.shape[1] > 1:
-        h = X.shape[1] // 2
-        X, Y, Z = cj.g1_add((X[:, :h], Y[:, :h], Z[:, :h]),
-                            (X[:, h:], Y[:, h:], Z[:, h:]))
-    out = cj.g1_unpack((jnp.asarray(np.asarray(X[:, 0])),
-                        jnp.asarray(np.asarray(Y[:, 0])),
-                        jnp.asarray(np.asarray(Z[:, 0]))))
+    # one scanned add along the segment-length axis: every segment
+    # reduces in the same program, whatever its padded length
+    X, Y, Z = cj.g1_sum_axis((X, Y, Z), 1)
+    out = cj.g1_unpack((jnp.asarray(np.asarray(X)),
+                        jnp.asarray(np.asarray(Y)),
+                        jnp.asarray(np.asarray(Z))))
     return out[:n_seg]
 
 

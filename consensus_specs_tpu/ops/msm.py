@@ -18,8 +18,8 @@ Two engines:
   is ONE compiled program — bucket accumulation does
   windows*(n + 255*(T-1)) point-adds total (~10x fewer field ops than
   the per-point double-and-add lanes) and pays a single device launch.
-- **Double-and-add lanes + host tree** (small n, and G2): per-point
-  scalar-mul lanes and a host-driven pairwise reduction.
+- **Double-and-add lanes + scanned sum** (small n, and G2): per-point
+  scalar-mul lanes and one scanned point-add reduction.
 
 deneb's `g1_lincomb` over the 4096-point Lagrange basis
 (polynomial-commitments.md:268) is the headline shape.
@@ -221,19 +221,6 @@ def _pippenger_g1(X, Y, Z, digits):
     return acc
 
 
-def _tree_sum_host(add_jit, prods):
-    """Pairwise tree reduction driven from the host: log2(m) launches of
-    one small jitted pairwise-add kernel (at halving shapes) instead of
-    unrolling the whole tree into a single giant graph — the unrolled
-    form is what pushed the 4096-point MSM compile past the bench tier
-    budget."""
-    X, Y, Z = prods
-    while X.shape[0] > 1:
-        h = X.shape[0] // 2
-        X, Y, Z = add_jit((X[:h], Y[:h], Z[:h]), (X[h:], Y[h:], Z[h:]))
-    return X[0], Y[0], Z[0]
-
-
 def g1_multi_exp(points, scalars):
     """sum_i scalars[i] * points[i] over G1; returns an oracle Point.
 
@@ -260,7 +247,7 @@ def g1_multi_exp(points, scalars):
     packed = cj.g1_pack(pts)
     bits = cj.scalars_to_bits(sc)
     prods = cj.g1_scalar_mul(packed, bits)
-    out = _tree_sum_host(cj.g1_add, prods)
+    out = cj.g1_sum_axis(prods, 0)
     X = np.asarray(out[0])[None]
     Y = np.asarray(out[1])[None]
     Z = np.asarray(out[2])[None]
@@ -331,9 +318,9 @@ def g2_multi_exp(points, scalars, label=None):
     fold of a fused flush's signature legs — sigpipe/fold.py), the
     padded ladder axis is partitioned over the verify mesh via
     `shard_jobs`: each device runs its slice of the scalar-mul scan,
-    and the halving-tree sum's first log2(D) rounds are the cross-shard
-    all-reduce.  Exact integer math throughout, so the sum is
-    byte-identical at any mesh width."""
+    and the scanned sum gathers the products across shards.  Exact
+    integer math throughout, so the sum is byte-identical at any mesh
+    width."""
     if len(points) != len(scalars):
         raise ValueError("g2_multi_exp: length mismatch")
     if not points:
@@ -351,6 +338,6 @@ def g2_multi_exp(points, scalars, label=None):
         X, Y, Z, bits = shard_verify.shard_jobs((*packed, bits), label)
         packed = (X, Y, Z)
     prods = cj.g2_scalar_mul(packed, bits)
-    out = _tree_sum_host(cj.g2_add, prods)
+    out = cj.g2_sum_axis(prods, 0)
     return cj.g2_unpack(tuple(
         jnp.asarray(np.asarray(c))[None] for c in out))[0]

@@ -24,8 +24,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .mesh import axis_size, enable_x64, shard_map
-
 from ..ops import curve_jax as cj
 from ..ops.sha256 import sha256_64byte
 
@@ -43,7 +41,7 @@ def sharded_balance_total(local_balances):
 
 
 def make_balance_total(mesh: Mesh):
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         sharded_balance_total, mesh=mesh,
         in_specs=P(AXIS), out_specs=P(), check_vma=False))
 
@@ -69,7 +67,7 @@ def sharded_merkle_root(local_chunks, local_depth: int):
 
 def make_merkle_root(mesh: Mesh, chunks_per_device: int):
     local_depth = int(np.log2(chunks_per_device))
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         partial(sharded_merkle_root, local_depth=local_depth), mesh=mesh,
         in_specs=P(AXIS, None), out_specs=P(), check_vma=False))
 
@@ -89,7 +87,7 @@ def sharded_g1_sum(X, Y, Z):
 
 
 def make_g1_sum(mesh: Mesh):
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         sharded_g1_sum, mesh=mesh,
         in_specs=(P(AXIS, None),) * 3, out_specs=(P(),) * 3,
         check_vma=False))
@@ -106,7 +104,7 @@ def sharded_g1_ring_sum(X, Y, Z):
     all-gather of per-chip partial MSM buckets" pattern of SURVEY §2.6;
     big MSMs shard their buckets exactly like this.
     """
-    n_dev = axis_size(AXIS)
+    n_dev = int(jax.lax.axis_size(AXIS))
     local = cj.point_sum_tree(cj.F1, (X, Y, Z))   # local partial
     perm = [(i, (i + 1) % n_dev) for i in range(n_dev)]
 
@@ -125,7 +123,7 @@ def sharded_g1_ring_sum(X, Y, Z):
 
 
 def make_g1_ring_sum(mesh: Mesh):
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         sharded_g1_ring_sum, mesh=mesh,
         in_specs=(P(AXIS, None),) * 3,
         out_specs=(P(AXIS, None),) * 3, check_vma=False))
@@ -141,7 +139,7 @@ def sharded_msm(X, Y, Z, bits):
     sharded_g1_ring_sum.  This is the in-path shape g1_lincomb uses
     when the mesh engine is enabled (deneb
     polynomial-commitments.md:268 over a device mesh)."""
-    n_dev = axis_size(AXIS)
+    n_dev = int(jax.lax.axis_size(AXIS))
     prods = cj.g1_scalar_mul((X, Y, Z), bits)
     local = cj.point_sum_tree(cj.F1, prods)
     perm = [(i, (i + 1) % n_dev) for i in range(n_dev)]
@@ -160,7 +158,7 @@ def make_msm(mesh: Mesh):
     """Compiled sharded MSM: points sharded over the mesh's device
     axis, scalar bit-planes alongside, one replicated-sum row per
     device out."""
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         sharded_msm, mesh=mesh,
         in_specs=(P(AXIS, None), P(AXIS, None), P(AXIS, None),
                   P(AXIS, None)),
@@ -221,7 +219,7 @@ def make_flag_set(mesh: Mesh, weight: int, weight_denominator: int,
     """Compiled flag pass over a validator axis sharded on `mesh`
     (reference collective; production epoch flags ride the fused
     ops.epoch_sweep dispatch)."""
-    jfn = jax.jit(shard_map(
+    jfn = jax.jit(jax.shard_map(
         partial(sharded_flag_set, weight=weight,
                 weight_denominator=weight_denominator,
                 head_flag=head_flag),
@@ -230,7 +228,7 @@ def make_flag_set(mesh: Mesh, weight: int, weight_denominator: int,
         out_specs=(P(AXIS), P(AXIS)), check_vma=False))
 
     def call(eff_incr, active_cur, eligible, unsl, base_per_incr, leak):
-        with enable_x64():
+        with jax.enable_x64(True):
             return jfn(eff_incr, active_cur, eligible, unsl,
                        jnp.int64(base_per_incr), jnp.bool_(leak))
     return call
@@ -271,14 +269,14 @@ def make_slashings(mesh: Mesh, electra: bool):
     """Compiled slashing sweep over a validator axis sharded on `mesh`
     (reference collective; production slashings ride the fused
     ops.epoch_sweep dispatch)."""
-    jfn = jax.jit(shard_map(
+    jfn = jax.jit(jax.shard_map(
         partial(sharded_slashings, electra=electra),
         mesh=mesh,
         in_specs=(P(AXIS), P(AXIS), P(), P(), P()),
         out_specs=P(AXIS), check_vma=False))
 
     def call(eff_incr, mask, adjusted_total, total_balance, increment):
-        with enable_x64():
+        with jax.enable_x64(True):
             return jfn(eff_incr, mask, jnp.int64(adjusted_total),
                        jnp.int64(total_balance), jnp.int64(increment))
     return call

@@ -13,39 +13,6 @@ import jax
 from jax.sharding import Mesh
 
 
-def shard_map(f, mesh: Mesh, in_specs, out_specs, check_vma: bool = False):
-    """`jax.shard_map` across jax versions: new jax exposes it at the
-    top level (with the replication check spelled `check_vma`); jax <
-    0.5 ships it as `jax.experimental.shard_map.shard_map` with the
-    same flag spelled `check_rep`.  Every shard_map in the repo routes
-    through here so the collectives run on both."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
-
-
-def axis_size(axis_name: str) -> int:
-    """Static mesh-axis size from inside a shard_map body.  jax < 0.5
-    has no `jax.lax.axis_size`; there, `psum(1, axis)` of a static
-    value folds to the concrete axis size at trace time (the ring
-    permutations below need a Python int, not a tracer)."""
-    if hasattr(jax.lax, "axis_size"):
-        return int(jax.lax.axis_size(axis_name))
-    return int(jax.lax.psum(1, axis_name))
-
-
-def enable_x64():
-    """`jax.enable_x64` across jax versions (jax < 0.5 keeps the
-    context manager under jax.experimental)."""
-    if hasattr(jax, "enable_x64"):
-        return jax.enable_x64()
-    from jax.experimental import enable_x64 as _enable_x64
-    return _enable_x64()
-
-
 def device_count() -> int:
     return len(jax.devices())
 

@@ -6,7 +6,7 @@ call consults the plan before running the device function.  Four fault
 kinds model the ways a real accelerator dispatch goes wrong:
 
 * ``raise``   — the dispatch dies with a `DeviceFault` (XLA runtime error,
-                relay disconnect, OOM): loud, immediate.
+                lost device, OOM): loud, immediate.
 * ``timeout`` — the dispatch hangs: the injected function sleeps past the
                 supervisor's watchdog deadline before answering.  Without
                 a supervisor it is merely slow — exactly like a real hang.

@@ -72,6 +72,16 @@ class PubkeyCache:
             self._cache[key] = point
         return point
 
+    def prime(self, items) -> None:
+        """Insert already-validated (compressed pubkey, G1 Point) pairs:
+        the registry's keys a node validated when they entered it, as
+        after a restart from its own snapshot.  Bounded like `get`."""
+        with self._lock:
+            for pubkey, point in items:
+                if len(self._cache) >= self._max:
+                    self._cache.pop(next(iter(self._cache)))
+                self._cache[bytes(pubkey)] = point
+
     def clear(self) -> None:
         with self._lock:
             self._cache.clear()

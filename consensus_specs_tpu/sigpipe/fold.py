@@ -87,9 +87,9 @@ def live() -> bool:
 def one_launch_live() -> bool:
     """Whether the WHOLE folded flush rides one compiled program per
     mesh device: folding on, device pairing kernels active (tpu
-    backend) and the fused single-program pairing mode resolved — on
-    CPU hosts the staged kernels win and the folded flush runs its
-    staged chain instead (hash sweep + weighting MSM + G2 fold + shard
+    backend) and the fused single-program pairing mode resolved —
+    under the default staged kernels the folded flush runs its staged
+    chain instead (hash sweep + weighting MSM + G2 fold + shard
     product), byte-identical verdicts either way."""
     if not live():
         return False

@@ -33,7 +33,7 @@ oracle — a mismatch quarantines the site and returns the oracle lanes.
 
 Public surface (everything else is engine-internal — speclint
 `epoch-scalar-bypass` flags outside access): ENABLED, SWEEP_SITE,
-scalar_epoch, fused_epoch, set_guard.
+scalar_epoch, numpy_epoch, fused_epoch, set_guard.
 """
 from __future__ import annotations
 
@@ -44,6 +44,7 @@ from math import isqrt
 import numpy as np
 
 ENABLED = True
+_ON_DEVICE = True
 
 SWEEP_SITE = "ops.epoch_sweep"
 
@@ -63,6 +64,19 @@ def scalar_epoch():
         yield
     finally:
         ENABLED = prev
+
+
+@contextlib.contextmanager
+def numpy_epoch():
+    """Temporarily run the fused engine's lanes on the numpy twin
+    instead of the device program — the host side of a device/host
+    differential run (no dispatch is made or counted)."""
+    global _ON_DEVICE
+    prev, _ON_DEVICE = _ON_DEVICE, False
+    try:
+        yield
+    finally:
+        _ON_DEVICE = prev
 
 
 def set_guard(rate: float, seed: int = 0) -> None:
@@ -536,8 +550,12 @@ def fused_epoch(spec, state) -> bool:
         METRICS.inc_labeled("epoch_sweep_fallbacks", _fallback_reason())
         return numpy_sweep(inp)
 
-    METRICS.inc("epoch_sweep_dispatches")
-    out = supervisor.dispatch(SWEEP_SITE, _device, _numpy_fallback)
+    if _ON_DEVICE:
+        METRICS.inc("epoch_sweep_dispatches")
+        out = supervisor.dispatch(SWEEP_SITE, _device, _numpy_fallback)
+    else:
+        used_fallback = True        # host lanes: nothing to guard
+        out = numpy_sweep(inp)
 
     # -- differential guard: sampled, device output only, pre-writeback
     if not used_fallback and _GUARD_RNG.random() < _GUARD_RATE:

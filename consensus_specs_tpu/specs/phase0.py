@@ -447,7 +447,27 @@ class Phase0Spec(Phase0ForkChoice, Phase0ValidatorDuties, BaseSpec):
     def get_randao_mix(self, state, epoch):
         return state.randao_mixes[epoch % self.EPOCHS_PER_HISTORICAL_VECTOR]
 
+    _ACTIVE_CACHE_SIZE = 4
+
     def get_active_validator_indices(self, state, epoch):
+        """Memoized by (registry root, epoch) like the reference's
+        `cache_this` (pysetup/spec_builders/phase0.py), but only on a
+        state the incremental merkle cache tracks, where that root is a
+        cache read: elsewhere the root would cost more than the scan.
+        Untracked states take the plain O(registry) pass.  Callers get
+        a fresh list."""
+        if not ssz_incremental.is_tracked(state):
+            return self._active_indices(state, epoch)
+        cache = self._caches.setdefault("active_indices_lru", {})
+        key = (bytes(hash_tree_root(state.validators)), int(epoch))
+        hit = cache.get(key)
+        if hit is None:
+            if len(cache) >= self._ACTIVE_CACHE_SIZE:
+                cache.pop(next(iter(cache)))
+            hit = cache[key] = self._active_indices(state, epoch)
+        return list(hit)
+
+    def _active_indices(self, state, epoch):
         return [uint64(i) for i, v in enumerate(state.validators)
                 if self.is_active_validator(v, epoch)]
 
