@@ -252,7 +252,7 @@ scenario-bench:
 
 # async pipelined flush engine alone (sigpipe/pipeline_async.py):
 # sustained multi-flush ingestion with overlap on vs off — asserts
-# byte-identical store roots + verdicts, 0 device idle gaps async, and
+# byte-identical store roots + verdicts, no inline flush async, and
 # <= 1 host<->device round-trip per fused merkle re-root; emits
 # PIPELINE_r01.json.  BENCH_PIPELINE_BACKEND=native and
 # BENCH_PIPELINE_MSGS=16 give an accelerator-less smoke run

@@ -496,8 +496,7 @@ CONCURRENCY = Concurrency(
                  note="worker singletons: creation and respawn checks"),
         LockSpec("sigpipe.ticket", _PA, "_lock", cls="FlushTicket",
                  kind="lock", guards=("_state", "_value", "_error"),
-                 note="ticket outcome; _done Event is the join handoff, "
-                      "_overlapped is written pre-publication only"),
+                 note="ticket outcome; _done Event is the join handoff"),
         LockSpec("sigpipe.worker_cv", _PA, "_cv", cls="_Worker",
                  kind="condition", guards=("_pending",),
                  note="queued+running job count; drain() waits on it"),
@@ -512,7 +511,7 @@ CONCURRENCY = Concurrency(
                  "consensus_specs_tpu.sigpipe.metrics", "_lock",
                  cls="Metrics",
                  guards=("counters", "labeled", "observations",
-                         "histograms", "timers")),
+                         "histograms")),
         # -- gossip: ingress vs the single drainer ---------------------
         LockSpec("gossip.ingress", _GP, "_ingress_lock",
                  cls="AdmissionPipeline",

@@ -4,8 +4,8 @@
 accelerator entry point routes through (utils/bls.py batch APIs and
 pairing check, sigpipe's hash-to-G2 sweep, ssz/merkle device hashing,
 kzg's device MSM).  With no supervisor enabled it is a two-attribute
-read plus the call — behavior byte-identical to the unwrapped code,
-including exception propagation.
+read and a profiler span around the call — behavior byte-identical to
+the unwrapped code, including exception propagation.
 
 With a supervisor enabled, each site gets a circuit breaker:
 
@@ -42,7 +42,7 @@ import threading
 from dataclasses import dataclass, field
 
 from ..sigpipe.metrics import METRICS
-from ..utils import nodectx
+from ..utils import nodectx, spans
 from ..utils.clock import MONOTONIC
 from ..utils.locks import named_lock, named_rlock
 from . import faults
@@ -355,10 +355,16 @@ def dispatch(site: str, device_fn, fallback_fn):
     semantics.  Fault injection (faults.py) wraps `device_fn` only — the
     fallback is the trusted path, which is exactly what makes
     trip-to-scalar a *recovery* and not a different failure mode.
+
+    The call, supervised or not, runs inside the profiler span
+    `dispatch:<site>` (utils/spans.py): host wall time over the device
+    call with its transfers and readback, or over the fallback taken
+    in its place.
     """
     plan = faults.active_plan()
     fn = plan.wrap(site, device_fn) if plan is not None else device_fn
     sup = _ACTIVE.get()
-    if sup is None:
-        return fn()
-    return sup.run(site, fn, fallback_fn)
+    with spans.span(spans.DISPATCH + site):
+        if sup is None:
+            return fn()
+        return sup.run(site, fn, fallback_fn)

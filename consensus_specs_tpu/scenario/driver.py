@@ -84,9 +84,7 @@ class ScenarioReport:
                 "incidents": [
                     (e["site"], e["event"], round(e["t"], 6))
                     for e in n["incidents"]],
-                "counters": {
-                    k: v for k, v in sorted(n["metrics"].items())
-                    if not k.endswith("_sec")},
+                "counters": dict(sorted(n["metrics"].items())),
             }
         return {
             "scenario": self.scenario.name,

@@ -1,4 +1,4 @@
-"""Lightweight pipeline metrics: counters, observations, wall-clock timers.
+"""Lightweight pipeline metrics: counters, observations, histograms.
 
 One module-global `METRICS` registry is shared by the collector, scheduler,
 bisection, caches and the resilience supervisor, so a single `snapshot()`
@@ -61,13 +61,9 @@ fallback), `merkle_cached_roots` (re-roots answered from cache with no
 hashing), and `merkle_guard_samples` / `merkle_guard_mismatches` for the
 differential guard.
 
-The async flush engine (pipeline_async.py) reports overlap here:
+The async flush engine (pipeline_async.py) reports here:
 `async_flushes` / `inline_flushes` (engine-worker vs caller-inline
-submits), `flush_overlap_ns` (worker wall time that ran while the
-caller did host work — overlapped flushes only, so scenario replays
-stay bit-identical), `device_idle_gaps` (host-sync stalls between a
-flush's verify dispatches on the synchronous path; pinned 0 with
-overlap on), `abandoned_flushes`, the power-of-two
+submits), `abandoned_flushes`, the power-of-two
 `flush_inflight_depth` histogram, and
 `merkle_device_round_trips` (host<->device transfers per merkle sweep:
 1 on the fused device-resident path, one per bulk level otherwise)
@@ -90,6 +86,12 @@ Python-level calls per epoch regardless of validator count), and
 lane-level differential guard that quarantines a corrupting device
 program before its outputs reach the state.
 
+Every entry counts events or observes sizes: none is read from a
+clock, so a (scenario, seed) replay snapshots bit-identically.  Where
+time goes is the profiler's business: the program's spans
+(utils/spans.py) mark the flush join, each device dispatch, the epoch
+phases, the merkle re-roots and the state copy.
+
 Histograms (`observe_hist`) bucket integer observations by
 power-of-two: the gossip admission layer records batch occupancy per
 flush here (`batch_occupancy`: how many signature sets each dispatch
@@ -102,8 +104,6 @@ singletons or mostly full windows?".
 from __future__ import annotations
 
 import json
-import time
-from contextlib import contextmanager
 
 from ..utils import nodectx
 from ..utils.locks import named_rlock
@@ -121,7 +121,6 @@ class Metrics:
             self.labeled: dict = {}
             self.observations: dict = {}
             self.histograms: dict = {}
-            self.timers: dict = {}
 
     # -- counters ------------------------------------------------------
     def inc(self, name: str, by: int = 1) -> None:
@@ -178,17 +177,6 @@ class Metrics:
         with self._lock:
             return dict(self.histograms.get(name, {}))
 
-    # -- timers --------------------------------------------------------
-    @contextmanager
-    def timer(self, name: str):
-        t0 = time.perf_counter()
-        try:
-            yield
-        finally:
-            elapsed = time.perf_counter() - t0
-            with self._lock:
-                self.timers[name] = self.timers.get(name, 0.0) + elapsed
-
     # -- export --------------------------------------------------------
     def snapshot(self) -> dict:
         with self._lock:
@@ -206,8 +194,6 @@ class Metrics:
                 out[f"{name}_hist"] = {
                     b: series[b]
                     for b in sorted(series, key=lambda s: int(s))}
-            for name, secs in self.timers.items():
-                out[f"{name}_sec"] = round(secs, 6)
             # derived rates the dashboards care about
             hits = self.counters.get("pubkey_cache_hits", 0)
             misses = self.counters.get("pubkey_cache_misses", 0)

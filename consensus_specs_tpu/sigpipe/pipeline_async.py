@@ -52,13 +52,11 @@ verifies genuinely pipeline (the mesh PR lifted the old blanket
 restriction; tests/test_node.py pins async-on/off byte parity of the
 served roots).
 
-Observability (sigpipe metrics): `async_flushes` / `inline_flushes`,
-`flush_overlap_ns` (wall nanoseconds of worker device work that
-overlapped caller-side host work), `device_idle_gaps` (host-sync
-stalls between a flush's verify dispatches that the async path would
-have overlapped — 0 on the async path, what `make pipeline-bench`
-pins), `abandoned_flushes`, and the power-of-two `flush_inflight_depth`
-histogram (tickets in flight at each submit).
+Observability: the sigpipe counters `async_flushes` / `inline_flushes`
+and `abandoned_flushes`, and the power-of-two `flush_inflight_depth`
+histogram (tickets in flight at each submit).  How long a caller waits
+is a profiler span (utils/spans.py): `sigpipe.join` around the wait in
+`FlushTicket.result`, `sigpipe.leg_join` around `Leg.get`.
 """
 from __future__ import annotations
 
@@ -69,6 +67,7 @@ import time
 
 from ..utils import nodectx
 from ..utils.locks import named_condition, named_lock
+from ..utils.spans import span
 from .metrics import METRICS
 
 # states a ticket moves through (monotonic)
@@ -126,9 +125,7 @@ class FlushTicket:
     as "no batch verdicts — deliver scalar" (the degradation ladder).
     """
 
-    __slots__ = ("label", "_done", "_state", "_value", "_error", "_lock",
-                 "_overlapped", "_submitted_ns", "_started_ns",
-                 "_finished_ns")
+    __slots__ = ("label", "_done", "_state", "_value", "_error", "_lock")
 
     def __init__(self, label: str):
         self.label = label
@@ -137,21 +134,15 @@ class FlushTicket:
         self._value = None
         self._error = None
         self._lock = named_lock("sigpipe.ticket")
-        self._overlapped = False    # ran on a worker (submit sets it)
-        self._submitted_ns = time.perf_counter_ns()
-        self._started_ns = None
-        self._finished_ns = None
 
     # -- worker side ---------------------------------------------------
     def _start(self) -> None:
         with self._lock:
             if self._state == PENDING:
                 self._state = RUNNING
-            self._started_ns = time.perf_counter_ns()
 
     def _finish(self, value, error) -> None:
         with self._lock:
-            self._finished_ns = time.perf_counter_ns()
             if self._state == ABANDONED:
                 # late completion of an abandoned flush: the outcome is
                 # dropped on the floor — never installed, never cached
@@ -188,26 +179,16 @@ class FlushTicket:
         """THE join barrier.  Returns the flush's value, or None when
         the flush failed, was abandoned, or `timeout` expired (the
         ticket is then abandoned — late completion is discarded)."""
-        if not self._done.wait(timeout):
+        with span("sigpipe.join"):
+            finished = self._done.wait(timeout)
+        if not finished:
             self.abandon()
             return None
-        join_ns = time.perf_counter_ns()
         with self._lock:
             if self._state != DONE:
                 if self._error is not None:
                     METRICS.inc("pipeline_errors")
                 return None
-            # overlap = worker wall time that ran while the caller was
-            # away doing host work (clamped to the submit->join window).
-            # Inline flushes record nothing: a wall-clock sample in the
-            # per-node counters would break the scenario tier's
-            # bit-identical (scenario, seed) replay fingerprint
-            if self._overlapped and self._started_ns is not None and \
-                    self._finished_ns is not None:
-                overlap = min(self._finished_ns, join_ns) \
-                    - max(self._started_ns, self._submitted_ns)
-                if overlap > 0:
-                    METRICS.inc("flush_overlap_ns", overlap)
             return self._value
 
 
@@ -343,7 +324,6 @@ def submit(fn, label: str = "flush") -> FlushTicket:
             ticket._finish(None, e)
         return ticket
     worker = _worker("flush")
-    ticket._overlapped = True
     METRICS.inc("async_flushes")
     METRICS.observe_hist("flush_inflight_depth", worker.depth() + 1)
     worker.put(ticket, fn)
@@ -363,7 +343,8 @@ class Leg:
         self._ticket = ticket
 
     def get(self):
-        self._ticket._done.wait()
+        with span("sigpipe.leg_join"):
+            self._ticket._done.wait()
         with self._ticket._lock:
             if self._ticket._error is not None:
                 raise self._ticket._error
@@ -384,14 +365,6 @@ def launch_leg(fn, label: str) -> Leg:
         return Leg(ticket)
     _worker("leg").put(ticket, fn)
     return Leg(ticket)
-
-
-def sync_gap() -> None:
-    """Record one host-sync stall between a flush's verify dispatches —
-    a point where the caller blocked on a device result that the async
-    path overlaps instead.  The pipeline bench pins this at 0 with the
-    engine on."""
-    METRICS.inc("device_idle_gaps")
 
 
 def drain(timeout: float = 30.0) -> bool:

@@ -82,6 +82,7 @@ from ..crypto import curve as cv
 from ..crypto.bls12_381 import _load_signature
 from ..crypto.curve import DecodeError
 from ..utils import bls
+from ..utils.spans import span
 from . import bisect as _bisect
 from . import fold
 from . import pipeline_async
@@ -206,8 +207,7 @@ def _verify_fused(sets, prepared, verdicts, strict=None, hash_leg=None):
     three of a flush's verify dispatches are in flight with no
     host-sync stall between them, and the first forced read is the
     verdict join below.  Without a leg (ASYNC_FLUSH=0, scenario
-    fleets) the dispatch order is byte-for-byte the historical one,
-    with the host stall it implies counted as a `device_idle_gaps`.
+    fleets) the dispatch order is byte-for-byte the historical one.
 
     With folding live (sigpipe/fold.py, the default) the flush emits
     N+1 pairing legs — N weighted aggregate legs plus ONE `e(-g1, S)`
@@ -221,7 +221,6 @@ def _verify_fused(sets, prepared, verdicts, strict=None, hash_leg=None):
     roots = [s.signing_root for s, _, _ in entries]
     hashes = None
     if hash_leg is None and not one_launch:
-        pipeline_async.sync_gap()
         hashes = _hash_roots(roots)
     coeffs = _coefficients(entries)
     neg_g1 = -cv.g1_generator()
@@ -387,41 +386,41 @@ def verify_sets(sets, mode: str = "fused"):
         return [True] * n
     verdicts: list = [None] * n
     guard_reason_for = None
-    with METRICS.timer("verify_sets"):
-        if mode == "per-set":
-            _verify_per_set(list(range(n)), sets, verdicts)
-        elif mode == "fused":
-            if fold.live():
-                # only the strict (required) sets ride the folded
-                # product; lax sets take the per-set batch APIs, so a
-                # mismatch there keeps the legacy label
-                guard_reason_for = (
-                    lambda i: "fold_mismatch" if sets[i].required
-                    else "guard_mismatch")
-            strict = [i for i, s in enumerate(sets) if s.required]
-            lax = [i for i, s in enumerate(sets) if not s.required]
-            hash_leg = None
-            if strict and pipeline_async.overlap_live() \
-                    and not fold.one_launch_live():
-                # overlapped leg: hash-to-G2 needs only the signing
-                # roots, so it launches BEFORE the G1 aggregation sweep
-                # and runs concurrently with the whole prepare chain.
-                # The one-launch folded path owns the cofactor sweep
-                # inside its single fused program — nothing to overlap
-                roots = [sets[i].signing_root for i in strict]
-                hash_leg = pipeline_async.launch_leg(
-                    lambda: _hash_roots(roots), "hash_to_g2")
+    if mode == "per-set":
+        _verify_per_set(list(range(n)), sets, verdicts)
+    elif mode == "fused":
+        if fold.live():
+            # only the strict (required) sets ride the folded
+            # product; lax sets take the per-set batch APIs, so a
+            # mismatch there keeps the legacy label
+            guard_reason_for = (
+                lambda i: "fold_mismatch" if sets[i].required
+                else "guard_mismatch")
+        strict = [i for i, s in enumerate(sets) if s.required]
+        lax = [i for i, s in enumerate(sets) if not s.required]
+        hash_leg = None
+        if strict and pipeline_async.overlap_live() \
+                and not fold.one_launch_live():
+            # overlapped leg: hash-to-G2 needs only the signing
+            # roots, so it launches BEFORE the G1 aggregation sweep
+            # and runs concurrently with the whole prepare chain.
+            # The one-launch folded path owns the cofactor sweep
+            # inside its single fused program — nothing to overlap
+            roots = [sets[i].signing_root for i in strict]
+            hash_leg = pipeline_async.launch_leg(
+                lambda: _hash_roots(roots), "hash_to_g2")
+        with span("sigpipe.prepare"):
             prepared = _prepare(strict, sets, verdicts)
-            if prepared:
-                _verify_fused(sets, prepared, verdicts, strict, hash_leg)
-            elif hash_leg is not None:
-                # every strict set screened out pre-pairing: drain the
-                # leg so nothing is left in flight past this flush
-                hash_leg.get()
-            if lax:
-                _verify_per_set(lax, sets, verdicts)
-        else:
-            raise ValueError(f"unknown sigpipe mode {mode!r}")
-        verdicts = _guard_verdicts(sets, verdicts,
-                                   reason_for=guard_reason_for)
+        if prepared:
+            _verify_fused(sets, prepared, verdicts, strict, hash_leg)
+        elif hash_leg is not None:
+            # every strict set screened out pre-pairing: drain the
+            # leg so nothing is left in flight past this flush
+            hash_leg.get()
+        if lax:
+            _verify_per_set(lax, sets, verdicts)
+    else:
+        raise ValueError(f"unknown sigpipe mode {mode!r}")
+    verdicts = _guard_verdicts(sets, verdicts,
+                               reason_for=guard_reason_for)
     return verdicts

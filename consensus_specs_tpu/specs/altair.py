@@ -11,7 +11,7 @@ from ..ssz import (
     uint8, uint64, boolean, Bitlist, Bitvector, Vector, List, Container,
     Bytes4, Bytes32, Bytes48, Bytes96, hash_tree_root, uint_to_bytes,
 )
-from ..utils import bls
+from ..utils import bls, spans
 from .light_client import LightClientMixin
 from .phase0 import Phase0Spec, integer_squareroot
 
@@ -336,12 +336,13 @@ class AltairSpec(LightClientMixin, Phase0Spec):
             # the effective-balance update; only the cheap tail resets
             # remain (eth1_data_reset commutes past the sweep: it clears
             # vote bookkeeping no fused pass reads or writes)
-            self.process_eth1_data_reset(state)
-            self.process_slashings_reset(state)
-            self.process_randao_mixes_reset(state)
-            self.process_historical_roots_update(state)
-            self.process_participation_flag_updates(state)
-            self.process_sync_committee_updates(state)
+            with spans.span("epoch.tail"):
+                self.process_eth1_data_reset(state)
+                self.process_slashings_reset(state)
+                self.process_randao_mixes_reset(state)
+                self.process_historical_roots_update(state)
+                self.process_participation_flag_updates(state)
+                self.process_sync_committee_updates(state)
             return
         self.process_justification_and_finalization(state)
         self.process_inactivity_updates(state)

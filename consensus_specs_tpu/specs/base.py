@@ -9,10 +9,32 @@ wins) directly in Python, with `super()` for upgrade deltas.
 """
 from __future__ import annotations
 
+import functools
 from contextlib import contextmanager
 
 from ..config import Config, load_config, load_preset
-from ..utils import bls
+from ..utils import bls, spans
+
+# the steps of `process_block`, each marked by a profiler span
+# (utils/spans.py) wherever a fork class defines it
+BLOCK_STEPS = {
+    "process_block_header": "block.header",
+    "process_randao": "block.randao",
+    "process_eth1_data": "block.eth1_data",
+    "process_operations": "block.operations",
+    "process_sync_aggregate": "block.sync_aggregate",
+    "process_withdrawals": "block.withdrawals",
+    "process_execution_payload": "block.execution_payload",
+    "process_execution_payload_header": "block.execution_payload_header",
+}
+
+
+def _spanned(method, name: str):
+    @functools.wraps(method)
+    def step(self, *args, **kwargs):
+        with spans.span(name):
+            return method(self, *args, **kwargs)
+    return step
 
 
 class BaseSpec:
@@ -21,6 +43,12 @@ class BaseSpec:
     # sigpipe verdict map (sigpipe/verify.py block_scope); None outside a
     # pipeline window, when every seam call is a plain scalar verify
     _sigpipe_verdicts = None
+
+    def __init_subclass__(cls, **kwargs):
+        super().__init_subclass__(**kwargs)
+        for attr, name in BLOCK_STEPS.items():
+            if attr in vars(cls):
+                setattr(cls, attr, _spanned(vars(cls)[attr], name))
 
     def __init__(self, preset_name: str = "mainnet",
                  config: Config | None = None):

@@ -8,6 +8,7 @@ from ..ssz import (
     ByteVector, Bytes4, Bytes20, Bytes32, Bytes48, Bytes96,
     hash_tree_root,
 )
+from ..utils import spans
 from .bellatrix import BellatrixSpec
 
 
@@ -280,12 +281,13 @@ class CapellaSpec(BellatrixSpec):
     def process_epoch(self, state) -> None:
         from . import epoch_fast
         if epoch_fast.fused_epoch(self, state):
-            self.process_eth1_data_reset(state)
-            self.process_slashings_reset(state)
-            self.process_randao_mixes_reset(state)
-            self.process_historical_summaries_update(state)
-            self.process_participation_flag_updates(state)
-            self.process_sync_committee_updates(state)
+            with spans.span("epoch.tail"):
+                self.process_eth1_data_reset(state)
+                self.process_slashings_reset(state)
+                self.process_randao_mixes_reset(state)
+                self.process_historical_summaries_update(state)
+                self.process_participation_flag_updates(state)
+                self.process_sync_committee_updates(state)
             return
         self.process_justification_and_finalization(state)
         self.process_inactivity_updates(state)

@@ -14,7 +14,7 @@ from ..ssz import (
     Bytes4, Bytes20, Bytes32, Bytes48, Bytes96,
     hash_tree_root, serialize,
 )
-from ..utils import bls
+from ..utils import bls, spans
 from .deneb import DenebSpec
 from .phase0 import bytes_to_uint64
 
@@ -454,12 +454,13 @@ class ElectraSpec(DenebSpec):
         if epoch_fast.fused_epoch(self, state):
             # fused_epoch ran the scalar registry + pending-deposit /
             # consolidation queues at their reference positions itself
-            self.process_eth1_data_reset(state)
-            self.process_slashings_reset(state)
-            self.process_randao_mixes_reset(state)
-            self.process_historical_summaries_update(state)
-            self.process_participation_flag_updates(state)
-            self.process_sync_committee_updates(state)
+            with spans.span("epoch.tail"):
+                self.process_eth1_data_reset(state)
+                self.process_slashings_reset(state)
+                self.process_randao_mixes_reset(state)
+                self.process_historical_summaries_update(state)
+                self.process_participation_flag_updates(state)
+                self.process_sync_committee_updates(state)
             return
         self.process_justification_and_finalization(state)
         self.process_inactivity_updates(state)

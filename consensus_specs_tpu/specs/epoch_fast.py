@@ -43,6 +43,8 @@ from math import isqrt
 
 import numpy as np
 
+from ..utils.spans import span
+
 ENABLED = True
 _ON_DEVICE = True
 
@@ -496,44 +498,45 @@ def fused_epoch(spec, state) -> bool:
         return False
     from ..sigpipe.metrics import METRICS
 
-    altair_family = bool(spec.is_post("altair"))
-    electra = bool(spec.is_post("electra"))
-    cur = int(spec.get_current_epoch(state))
-    prev = int(spec.get_previous_epoch(state))
-    genesis = int(spec.GENESIS_EPOCH)
-    do_rewards = cur != genesis
-    incr = int(spec.EFFECTIVE_BALANCE_INCREMENT)
-    arr = StateArrays(state)
-    tb = arr.total_active_balance(cur, incr)
+    with span("epoch.collect"):
+        altair_family = bool(spec.is_post("altair"))
+        electra = bool(spec.is_post("electra"))
+        cur = int(spec.get_current_epoch(state))
+        prev = int(spec.get_previous_epoch(state))
+        genesis = int(spec.GENESIS_EPOCH)
+        do_rewards = cur != genesis
+        incr = int(spec.EFFECTIVE_BALANCE_INCREMENT)
+        arr = StateArrays(state)
+        tb = arr.total_active_balance(cur, incr)
 
-    # -- host prefix: justification (checkpoint/bit mutations only) ----
-    part_prev = None
-    masks_prev = None
-    if altair_family:
-        part_prev = _participation(state, "previous", n)
-    elif do_rewards:
-        masks_prev = phase0_attestation_masks(spec, state, prev)
-    if cur > genesis + 1:
+        # -- host prefix: justification (checkpoint/bit mutations only) ----
+        part_prev = None
+        masks_prev = None
         if altair_family:
-            tflag = int(spec.TIMELY_TARGET_FLAG_INDEX)
-            prev_m = (arr.active(prev)
-                      & (((part_prev >> tflag) & 1) == 1) & ~arr.slashed)
-            part_cur = _participation(state, "current", n)
-            cur_m = (arr.active(cur)
-                     & (((part_cur >> tflag) & 1) == 1) & ~arr.slashed)
-        else:
-            prev_m = masks_prev[1] & ~arr.slashed
-            cur_m = phase0_attestation_masks(
-                spec, state, cur, targets_only=True)[1] & ~arr.slashed
-        spec.weigh_justification_and_finalization(
-            state, tb,
-            max(incr, int(arr.eff[prev_m].sum())),
-            max(incr, int(arr.eff[cur_m].sum())))
+            part_prev = _participation(state, "previous", n)
+        elif do_rewards:
+            masks_prev = phase0_attestation_masks(spec, state, prev)
+        if cur > genesis + 1:
+            if altair_family:
+                tflag = int(spec.TIMELY_TARGET_FLAG_INDEX)
+                prev_m = (arr.active(prev)
+                          & (((part_prev >> tflag) & 1) == 1) & ~arr.slashed)
+                part_cur = _participation(state, "current", n)
+                cur_m = (arr.active(cur)
+                         & (((part_cur >> tflag) & 1) == 1) & ~arr.slashed)
+            else:
+                prev_m = masks_prev[1] & ~arr.slashed
+                cur_m = phase0_attestation_masks(
+                    spec, state, cur, targets_only=True)[1] & ~arr.slashed
+            spec.weigh_justification_and_finalization(
+                state, tb,
+                max(incr, int(arr.eff[prev_m].sum())),
+                max(incr, int(arr.eff[cur_m].sum())))
 
-    # leak/finality/finalized all read the POST-justification state
-    leak = bool(spec.is_in_inactivity_leak(state)) if do_rewards else False
-    inp = _collect(spec, state, arr, part_prev, masks_prev,
-                   do_rewards, leak, tb, cur, prev)
+        # leak/finality/finalized all read the POST-justification state
+        leak = bool(spec.is_in_inactivity_leak(state)) if do_rewards else False
+        inp = _collect(spec, state, arr, part_prev, masks_prev,
+                       do_rewards, leak, tb, cur, prev)
 
     # -- the ONE dispatch ----------------------------------------------
     from ..resilience import supervisor
@@ -577,51 +580,52 @@ def fused_epoch(spec, state) -> bool:
         new_bal, new_eff, elig_q, eject, ready = out
         new_scores = None
 
-    # -- batched writeback + the rare scalar mutations ------------------
-    wb = 0
-    if new_scores is not None:
-        wb += _bulk_write(state.inactivity_scores,
-                          inp.cols["scores"], new_scores)
-    wb += _bulk_write(state.balances, arr.balances, new_bal)
+    with span("epoch.writeback"):
+        # -- batched writeback + the rare scalar mutations ------------------
+        wb = 0
+        if new_scores is not None:
+            wb += _bulk_write(state.inactivity_scores,
+                              inp.cols["scores"], new_scores)
+        wb += _bulk_write(state.balances, arr.balances, new_bal)
 
-    if not electra:
-        _apply_registry(spec, state, cur, arr, elig_q, eject, ready)
-        changed = np.nonzero(new_eff != arr.eff)[0]
-        for i in changed.tolist():
-            state.validators[i].effective_balance = int(new_eff[i])
-        wb += int(changed.size)
-    else:
-        # electra's single-pass registry and its deposit/consolidation
-        # queues stay scalar spec calls at their reference positions;
-        # they read effective balances (untouched so far) and may move
-        # balances or append validators — the sweep's hysteresis lanes
-        # stay valid exactly for the untouched validators
-        spec.process_registry_updates(state)
-        spec.process_pending_deposits(state)
-        spec.process_pending_consolidations(state)
-        n2 = len(state.validators)
-        bal_after = np.fromiter(
-            (int(b) for b in state.balances), np.int64, n2)
-        moved = np.ones(n2, bool)
-        moved[:n] = bal_after[:n] != new_bal
-        untouched = np.nonzero(~moved[:n] & (new_eff != arr.eff))[0]
-        for i in untouched.tolist():
-            state.validators[i].effective_balance = int(new_eff[i])
-        wb += int(untouched.size)
-        h = incr // int(spec.HYSTERESIS_QUOTIENT)
-        down = h * int(spec.HYSTERESIS_DOWNWARD_MULTIPLIER)
-        up = h * int(spec.HYSTERESIS_UPWARD_MULTIPLIER)
-        comp_prefix = int.from_bytes(
-            bytes(spec.COMPOUNDING_WITHDRAWAL_PREFIX), "big")
-        for i in np.nonzero(moved)[0].tolist():
-            v = state.validators[i]
-            bal_i = int(bal_after[i])
-            eff_i = int(v.effective_balance)
-            if bal_i + down < eff_i or eff_i + up < bal_i:
-                max_eb = (int(spec.MAX_EFFECTIVE_BALANCE_ELECTRA)
-                          if v.withdrawal_credentials[0] == comp_prefix
-                          else int(spec.MIN_ACTIVATION_BALANCE))
-                v.effective_balance = min(bal_i - bal_i % incr, max_eb)
+        if not electra:
+            _apply_registry(spec, state, cur, arr, elig_q, eject, ready)
+            changed = np.nonzero(new_eff != arr.eff)[0]
+            for i in changed.tolist():
+                state.validators[i].effective_balance = int(new_eff[i])
+            wb += int(changed.size)
+        else:
+            # electra's single-pass registry and its deposit/consolidation
+            # queues stay scalar spec calls at their reference positions;
+            # they read effective balances (untouched so far) and may move
+            # balances or append validators — the sweep's hysteresis lanes
+            # stay valid exactly for the untouched validators
+            spec.process_registry_updates(state)
+            spec.process_pending_deposits(state)
+            spec.process_pending_consolidations(state)
+            n2 = len(state.validators)
+            bal_after = np.fromiter(
+                (int(b) for b in state.balances), np.int64, n2)
+            moved = np.ones(n2, bool)
+            moved[:n] = bal_after[:n] != new_bal
+            untouched = np.nonzero(~moved[:n] & (new_eff != arr.eff))[0]
+            for i in untouched.tolist():
+                state.validators[i].effective_balance = int(new_eff[i])
+            wb += int(untouched.size)
+            h = incr // int(spec.HYSTERESIS_QUOTIENT)
+            down = h * int(spec.HYSTERESIS_DOWNWARD_MULTIPLIER)
+            up = h * int(spec.HYSTERESIS_UPWARD_MULTIPLIER)
+            comp_prefix = int.from_bytes(
+                bytes(spec.COMPOUNDING_WITHDRAWAL_PREFIX), "big")
+            for i in np.nonzero(moved)[0].tolist():
+                v = state.validators[i]
+                bal_i = int(bal_after[i])
+                eff_i = int(v.effective_balance)
+                if bal_i + down < eff_i or eff_i + up < bal_i:
+                    max_eb = (int(spec.MAX_EFFECTIVE_BALANCE_ELECTRA)
+                              if v.withdrawal_credentials[0] == comp_prefix
+                              else int(spec.MIN_ACTIVATION_BALANCE))
+                    v.effective_balance = min(bal_i - bal_i % incr, max_eb)
 
-    METRICS.inc("epoch_writeback_elems", wb)
+        METRICS.inc("epoch_writeback_elems", wb)
     return True

@@ -20,7 +20,7 @@ from ..ssz import (
 )
 from ..ssz import incremental as ssz_incremental
 from ..ssz.merkle import is_valid_merkle_branch as _merkle_branch_ok
-from ..utils import bls
+from ..utils import bls, spans
 from ..utils.hash import hash as sha256_hash
 from .base import BaseSpec
 from .fork_choice import Phase0ForkChoice
@@ -718,11 +718,12 @@ class Phase0Spec(Phase0ForkChoice, Phase0ValidatorDuties, BaseSpec):
             # the effective-balance update; only the cheap tail resets
             # remain (eth1_data_reset commutes past the sweep: it clears
             # vote bookkeeping no fused pass reads or writes)
-            self.process_eth1_data_reset(state)
-            self.process_slashings_reset(state)
-            self.process_randao_mixes_reset(state)
-            self.process_historical_roots_update(state)
-            self.process_participation_record_updates(state)
+            with spans.span("epoch.tail"):
+                self.process_eth1_data_reset(state)
+                self.process_slashings_reset(state)
+                self.process_randao_mixes_reset(state)
+                self.process_historical_roots_update(state)
+                self.process_participation_record_updates(state)
             return
         self.process_justification_and_finalization(state)
         self.process_rewards_and_penalties(state)

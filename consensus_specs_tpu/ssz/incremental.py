@@ -53,6 +53,7 @@ from __future__ import annotations
 import random
 import threading
 
+from ..utils.spans import span
 from . import merkle as _merkle
 from . import types as _types
 from .merkle import ZERO_CHUNK, ZERO_HASHES, chunk_depth
@@ -745,7 +746,8 @@ def _root_hook(view):
     if cache.built and not cache.dirty and cache.root is not None:
         _METRICS.inc("merkle_cached_roots")
         return cache.root
-    return _recompute(view, cache)
+    with span("merkle.reroot"):
+        return _recompute(view, cache)
 
 
 def _recompute(view, cache: _MCache) -> bytes:

@@ -12,6 +12,7 @@ ByteVector[N], ByteList[N], Vector[T, N], List[T, N], Container, Union[...].
 """
 from __future__ import annotations
 
+from ..utils.spans import span
 from .merkle import (
     merkleize_chunks, mix_in_length, mix_in_selector, ZERO_CHUNK,
 )
@@ -954,7 +955,8 @@ class Container(SSZType):
         return obj
 
     def copy(self):
-        return _structural_copy(self)
+        with span("ssz.copy"):
+            return _structural_copy(self)
 
     def hash_tree_root(self) -> bytes:
         if not self._field_names:

@@ -17,8 +17,8 @@ The contract under test:
   in ONE host<->device round-trip, byte-identical to the per-level
   path and the full-rebuild oracle.
 * Scenario fleets degrade to inline execution (the nodectx stack is
-  process-global), and `device_idle_gaps` pins the overlap: >0 sync,
-  0 async.
+  process-global), and the flush counters pin the overlap: every
+  synchronous flush runs inline, every async one on the engine worker.
 """
 import threading
 
@@ -266,12 +266,13 @@ def _run_ingestion(spec, ingestion, overlap: bool, windows: int = 3):
 
 def test_async_ingestion_matches_sync_byte_for_byte(spec, ingestion):
     sync_v, sync_fp = _run_ingestion(spec, ingestion, overlap=False)
-    assert METRICS.count("device_idle_gaps") > 0     # sync stalls counted
+    assert METRICS.count("inline_flushes") > 0      # sync: on the caller
+    assert METRICS.count("async_flushes") == 0
     METRICS.reset()
     async_v, async_fp = _run_ingestion(spec, ingestion, overlap=True)
     assert async_v == sync_v
     assert async_fp == sync_fp
-    assert METRICS.count("device_idle_gaps") == 0    # overlap: no stalls
+    assert METRICS.count("inline_flushes") == 0     # overlap: none inline
     assert METRICS.count("async_flushes") > 0
 
 
