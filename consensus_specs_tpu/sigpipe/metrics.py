@@ -55,6 +55,9 @@ bounded by the state tree height), `merkle_chunks_hashed` (2-to-1 hashes
 the sweeps performed — O(diff · log state), the number the merkle bench
 asserts scales with diff size), `merkle_dirty_nodes` (dirty leaf chunks
 swept) with the power-of-two `merkle_dirty_occupancy` histogram,
+`merkle_flat_roots` (roots of flat sequence elements — registry
+records, eth1 votes — planned in sweeps from their field values, as
+such elements keep no cache of their own),
 `merkle_cache_builds` (first full builds of a tracked view),
 `merkle_full_rebuilds` (legacy full re-roots taken as the sweep-site
 fallback), `merkle_cached_roots` (re-roots answered from cache with no

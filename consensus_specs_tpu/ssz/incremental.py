@@ -30,6 +30,19 @@ Cache layout (per tracked composite view, stored at ``view._mcache``):
   state copies (txn/ overlay discipline) can never corrupt each other's
   caches — a rolled-back copy just drops its private dirty set.
 
+Flat elements keep no cache.  An element of a sequence whose element
+type is a *flat* container (``types.is_flat_container``: fixed-size,
+every field a basic type or a ``ByteVector`` — ``Validator``,
+``Eth1Data``, ``HistoricalSummary``, electra's pending queues) has no
+``_mcache``: its root lives only in the sequence's ``levels[0]``, and a
+sweep that finds it dirty plans its whole subtree again from its field
+values (``_plan_flat``: 7 jobs for 8 fields, with a host root only for
+a ``ByteVector`` over 32 bytes).  Its back-link to the sequence is the
+two ``Container`` slots ``_mc_seq`` / ``_mc_idx``, so a registry element
+holds no tracked object beyond its field dict and a state copy copies
+only that dict per element.  Elements whose type holds mutable views
+(phase0's ``PendingAttestation``) keep their own caches.
+
 The sweep is a real resilience seam: ``dispatch("ssz.merkle_sweep",
 device_fn, fallback_fn)`` where the fallback is the legacy full Python
 re-root (byte-identical by construction, caches unwritten, dirty sets
@@ -45,6 +58,7 @@ site quarantine) on mismatch, exactly like the BLS guard.
 Observability (sigpipe metrics registry): ``merkle_sweep_dispatches``,
 ``merkle_sweep_levels``, ``merkle_chunks_hashed``, ``merkle_dirty_nodes``
 (+ power-of-two ``merkle_dirty_occupancy`` histogram),
+``merkle_flat_roots`` (flat-element roots planned in sweeps),
 ``merkle_full_rebuilds``, ``merkle_cached_roots``,
 ``merkle_guard_samples`` / ``merkle_guard_mismatches``.
 """
@@ -52,6 +66,8 @@ from __future__ import annotations
 
 import random
 import threading
+from collections import deque
+from itertools import repeat
 
 from ..utils.spans import span
 from . import merkle as _merkle
@@ -59,7 +75,7 @@ from . import types as _types
 from .merkle import ZERO_CHUNK, ZERO_HASHES, chunk_depth
 from .types import (
     Bitlist, Bits, Bitvector, Container, List, Union, Vector,
-    _MUTABLE_VIEW_BASES, _Sequence, is_basic_type,
+    _MUTABLE_VIEW_BASES, _Sequence, is_basic_type, is_flat_container,
 )
 
 SWEEP_SITE = "ssz.merkle_sweep"
@@ -309,13 +325,37 @@ def _invalidate_root(view) -> None:
         _mark(parent[0], parent[1])
 
 
+_SET_SEQ = Container._mc_seq.__set__
+_SET_IDX = Container._mc_idx.__set__
+
+
+def _flat_elems(view) -> bool:
+    """Whether `view` is a sequence of flat containers: its elements
+    keep no cache, only the back-link `_mc_seq` / `_mc_idx`."""
+    return isinstance(view, _Sequence) and is_flat_container(view.ELEM_TYPE)
+
+
 def _attach(parent, idx: int, child) -> None:
-    if isinstance(child, _MUTABLE_VIEW_BASES):
+    if not isinstance(child, _MUTABLE_VIEW_BASES):
+        return
+    if _flat_elems(parent):
+        # the two `Container` slots; a cache the element brought along
+        # (a copy of a cached container) is dropped: its root is the
+        # sequence's leaf now
+        if getattr(child, "_mcache", None) is not None:
+            del child.__dict__["_mcache"]
+        _SET_SEQ(child, parent)
+        _SET_IDX(child, idx)
+    else:
         child.__dict__["_mc_parent"] = (parent, idx)
 
 
 def _detach(parent, child) -> None:
     if isinstance(child, _MUTABLE_VIEW_BASES):
+        if _flat_elems(parent):
+            if getattr(child, "_mc_seq", None) is parent:
+                _SET_SEQ(child, None)
+            return
         link = child.__dict__.get("_mc_parent")
         if link is not None and link[0] is parent:
             child.__dict__["_mc_parent"] = None
@@ -327,6 +367,12 @@ class _Hooks:
 
     @staticmethod
     def on_container_set(view, idx, old, new):
+        if is_flat_container(type(view)):
+            seq = getattr(view, "_mc_seq", None)
+            if seq is not None:
+                # a flat element: its root is the sequence's leaf chunk
+                _mark(seq, view._mc_idx)
+                return
         if _cache_of(view) is None:
             return
         if old is not new:
@@ -428,7 +474,14 @@ class _Hooks:
             for j, name in enumerate(type(dst)._field_names):
                 _attach(dst, j, dst._values[name])
         elif isinstance(dst, _Sequence):
-            if not is_basic_type(dst.ELEM_TYPE):
+            if is_flat_container(dst.ELEM_TYPE):
+                # fresh element copies (types._copy_flat): no cache to
+                # drop; the slots are set through their descriptors, in
+                # C loops, once per element of the registry
+                elems = dst._elems
+                deque(map(_SET_SEQ, elems, repeat(dst)), 0)
+                deque(map(_SET_IDX, elems, range(len(elems))), 0)
+            elif issubclass(dst.ELEM_TYPE, _MUTABLE_VIEW_BASES):
                 for j, child in enumerate(dst._elems):
                     _attach(dst, j, child)
         elif isinstance(dst, Union):
@@ -503,13 +556,15 @@ class _Sweep:
     subtrees of the view graph at once.  A job ref is (round, index);
     a literal ref is the bytes themselves (round 0)."""
 
-    __slots__ = ("rounds", "writebacks", "finals", "dirty_leaves")
+    __slots__ = ("rounds", "writebacks", "finals", "dirty_leaves",
+                 "flat_roots")
 
     def __init__(self):
         self.rounds = []       # rounds[r] = [(left_ref, right_ref), ...]
         self.writebacks = []   # (cache, level, idx, ref)
         self.finals = []       # (cache, leaf_count, root_ref)
         self.dirty_leaves = 0
+        self.flat_roots = 0    # flat-element roots planned (_plan_flat)
 
     def job(self, left, right):
         r = 0
@@ -619,8 +674,30 @@ def _leaf_ref(sw: _Sweep, view, i: int):
         child = view._elems[i]
     if isinstance(child, _MUTABLE_VIEW_BASES):
         _attach(view, i, child)
+        if _flat_elems(view):
+            return _plan_flat(sw, child)
         return _plan_view(sw, child)
     return bytes(child.hash_tree_root())
+
+
+def _plan_flat(sw: _Sweep, elem):
+    """Plan the root of a flat sequence element from its field values:
+    its whole subtree as hash jobs of this sweep (no cache to reuse).
+    Each field's chunk is its root as `_leaf_ref` gives an immutable
+    child: the packed value, hashed on the host only for a `ByteVector`
+    over 32 bytes."""
+    values = elem._values
+    layer = [bytes(values[name].hash_tree_root())
+             for name in type(elem)._field_names]
+    d = 0
+    while len(layer) > 1:
+        if len(layer) & 1:
+            layer.append(ZERO_HASHES[d])
+        layer = [sw.job(layer[k], layer[k + 1])
+                 for k in range(0, len(layer), 2)]
+        d += 1
+    sw.flat_roots += 1
+    return layer[0]
 
 
 def _level_hash(data: bytes) -> tuple:
@@ -789,6 +866,7 @@ def _recompute(view, cache: _MCache) -> bytes:
                      sum(len(jobs) for jobs in sw.rounds))
         _METRICS.inc("merkle_sweep_levels", len(sw.rounds))
         _METRICS.inc("merkle_dirty_nodes", sw.dirty_leaves)
+        _METRICS.inc("merkle_flat_roots", sw.flat_roots)
         _METRICS.observe_hist("merkle_dirty_occupancy", sw.dirty_leaves)
         _commit(sw, outs_box[0])
 

@@ -814,6 +814,10 @@ class Container(SSZType):
     """
     _field_names: tuple = ()
     _field_types: tuple = ()
+    # the back-link of a flat element of a tracked sequence
+    # (ssz/incremental.py): slots, so linking one neither materialises
+    # the instance dict nor adds a tracked object
+    __slots__ = ("_mc_seq", "_mc_idx")
 
     def __init_subclass__(cls, **kwargs):
         super().__init_subclass__(**kwargs)
@@ -867,9 +871,12 @@ class Container(SSZType):
         object.__setattr__(self, "_values", values)
 
     def __getattr__(self, name):
-        # only called when normal lookup fails
-        values = self.__dict__.get("_values")
-        if values is not None and name in values:
+        # only called when normal lookup fails; reads `_values` through
+        # normal lookup, so a miss never materialises the instance dict
+        if name == "_values":
+            raise AttributeError(name)
+        values = self._values
+        if name in values:
             return values[name]
         raise AttributeError(name)
 
@@ -1076,6 +1083,30 @@ null = None
 # immutable Python objects and safe to share.
 _MUTABLE_VIEW_BASES = (_Sequence, Container, Bits, Union)
 
+_FLAT: dict = {}
+
+
+def is_flat_container(t) -> bool:
+    """Whether `t` is a *flat* container: fixed-size, with at least one
+    field, every field an immutable leaf (a basic type or a
+    `ByteVector`).  A copy of one is a copy of its field dict, and its
+    root is a function of its field values alone (ssz/incremental.py
+    keeps no cache for the flat elements of a sequence)."""
+    flat = _FLAT.get(t)
+    if flat is None:
+        flat = (isinstance(t, type) and issubclass(t, Container)
+                and bool(t._field_types)
+                and all(is_basic_type(f) or issubclass(f, ByteVector)
+                        for f in t._field_types))
+        _FLAT[t] = flat
+    return flat
+
+
+def _copy_flat(e):
+    obj = type(e).__new__(type(e))
+    object.__setattr__(obj, "_values", dict(e._values))
+    return obj
+
 
 def _structural_copy(v):
     """Deep copy of a composite view WITHOUT the serialize round-trip of
@@ -1087,11 +1118,16 @@ def _structural_copy(v):
     When the source carries an incremental-merkleization cache, the copy
     shares it copy-on-write (ssz/incremental.on_copy): the level arrays
     are shared until either side's next sweep needs to write, so a
-    transactional state copy costs no re-hashing."""
+    transactional state copy costs no re-hashing.  The elements of a
+    sequence of flat containers (`is_flat_container`) carry no cache,
+    so each is copied as its field dict alone."""
     if isinstance(v, _Sequence):
         t = v.ELEM_TYPE
         if is_basic_type(t) or not issubclass(t, _MUTABLE_VIEW_BASES):
             obj = type(v)._from_elems(list(v._elems))
+        elif is_flat_container(t):
+            # flat elements carry no cache to share: copy their fields
+            obj = type(v)._from_elems([_copy_flat(e) for e in v._elems])
         else:
             obj = type(v)._from_elems(
                 [_structural_copy(e) for e in v._elems])

@@ -16,9 +16,14 @@ The contract under test:
 * Resilience: a faulted/broken-open sweep site degrades to the legacy
   full Python re-root with identical bytes; a corrupted sweep is caught
   by the differential guard, which quarantines the caches.
+* Flat elements: the elements of a sequence of flat containers (a
+  registry) keep no cache of their own and hold no more tracked objects
+  than with the mode off, plus at most one; the differential tests run
+  over such a registry too (the `registry` cases).
 * ZERO_HASHES has one source of truth (merkle.py), shared by proofs.py
   and the deposit-contract model.
 """
+import gc
 from random import Random
 
 import pytest
@@ -29,8 +34,8 @@ from consensus_specs_tpu.resilience.supervisor import OPEN, QUARANTINED
 from consensus_specs_tpu.sigpipe import METRICS
 from consensus_specs_tpu.specs import get_spec
 from consensus_specs_tpu.ssz import (
-    Bitlist, Bitvector, Bytes32, Container, List, Union, Vector,
-    hash_tree_root, incremental, uint8, uint64,
+    Bitlist, Bitvector, Bytes32, Bytes48, Container, List, Union, Vector,
+    boolean, hash_tree_root, incremental, uint8, uint64,
 )
 from consensus_specs_tpu.test_infra import disable_bls
 from consensus_specs_tpu.test_infra.attestations import get_valid_attestation
@@ -149,18 +154,129 @@ def _mutate_once(rng: Random, z: Zoo) -> None:
     rng.choice(ops)()
 
 
+class Record(Container):
+    """Registry-like flat element: 8 fields, a Bytes48 among them."""
+    pubkey: Bytes48
+    creds: Bytes32
+    balance: uint64
+    slashed: boolean
+    eligible: uint64
+    active: uint64
+    exit: uint64
+    withdrawable: uint64
+
+
+class Vote(Container):
+    """Flat, with an odd field count (a zero-padded subtree)."""
+    deposit_root: Bytes32
+    count: uint64
+    block_hash: Bytes32
+
+
+class Pending(Container):
+    """Holds mutable views: its elements keep their own caches."""
+    bits: Bitlist[64]
+    data: Checkpoint
+
+
+class Registry(Container):
+    slot: uint64
+    records: List[Record, 1 << 40]
+    balances: List[uint64, 1 << 40]
+    votes: List[Vote, 64]
+    cps: List[Checkpoint, 8]
+    pending: List[Pending, 16]
+    cp: Checkpoint      # a flat container as a field keeps its cache
+
+
+def random_record(rng: Random) -> Record:
+    return Record(pubkey=Bytes48(rng.randbytes(48)),
+                  creds=Bytes32(rng.randbytes(32)),
+                  balance=rng.randrange(1 << 40),
+                  slashed=rng.random() < 0.1,
+                  eligible=rng.randrange(100), active=rng.randrange(100),
+                  exit=rng.randrange(1 << 64),
+                  withdrawable=rng.randrange(1 << 64))
+
+
+def random_registry(rng: Random, n: int | None = None) -> Registry:
+    r = Registry(slot=rng.randrange(1 << 20),
+                 cp=Checkpoint(epoch=rng.randrange(9)))
+    for _ in range(rng.randrange(1, 24) if n is None else n):
+        r.records.append(random_record(rng))
+        r.balances.append(rng.randrange(1 << 40))
+    for _ in range(rng.randrange(0, 5)):
+        r.votes.append(Vote(count=rng.randrange(1 << 20),
+                            block_hash=Bytes32(rng.randbytes(32))))
+    for _ in range(rng.randrange(0, 3)):
+        r.pending.append(Pending(bits=[rng.random() < 0.5
+                                       for _ in range(rng.randrange(64))]))
+    return r
+
+
+def _mutate_registry(rng: Random, r: Registry) -> None:
+    """One random mutation of a registry: field set on an element,
+    element replace, append, middle pop, on flat and nested elements."""
+    n = len(r.records)
+    ops = [
+        lambda: setattr(r, "slot", uint64(rng.randrange(1 << 20))),
+        lambda: setattr(r.cp, "epoch", uint64(rng.randrange(1000))),
+        # a copy of a cached flat container enters a flat sequence
+        lambda: r.cps.append(r.cp) if len(r.cps) < 8 else r.cps.pop(0),
+    ]
+    if n < 40:
+        ops.append(lambda: r.records.append(random_record(rng)))
+    if n:
+        i = rng.randrange(n)
+        ops += [
+            lambda: setattr(r.records[i], "exit",
+                            uint64(rng.randrange(1 << 64))),
+            lambda: setattr(r.records[i], "pubkey",
+                            Bytes48(rng.randbytes(48))),
+            lambda: setattr(r.records[i], "slashed", rng.random() < 0.5),
+            lambda: r.records.__setitem__(i, random_record(rng)),
+            lambda: r.records.pop(i),
+        ]
+    if len(r.votes) < 64:
+        ops.append(lambda: r.votes.append(Vote(count=rng.randrange(99))))
+    if len(r.votes):
+        j = rng.randrange(len(r.votes))
+        ops += [lambda: setattr(r.votes[j], "count", uint64(rng.randrange(99))),
+                lambda: r.votes.pop(j)]
+    if len(r.cps):
+        k = rng.randrange(len(r.cps))
+        ops.append(lambda: setattr(r.cps[k], "root",
+                                   Bytes32(rng.randbytes(32))))
+    if len(r.pending):
+        m = rng.randrange(len(r.pending))
+        ops += [lambda: r.pending[m].bits.append(True)
+                if len(r.pending[m].bits) < 64 else r.pending.pop(m),
+                lambda: setattr(r.pending[m].data, "epoch",
+                                uint64(rng.randrange(99)))]
+    rng.choice(ops)()
+
+
+# each differential test runs over both shapes: the zoo of every
+# composite family, and a registry of flat elements
+KINDS = {"zoo": (random_zoo, _mutate_once),
+         "registry": (random_registry, _mutate_registry)}
+kinds = pytest.mark.parametrize("kind", sorted(KINDS))
+
+
 # ---------------------------------------------------------------------------
 # randomized mutation parity
 # ---------------------------------------------------------------------------
 
+@kinds
 @pytest.mark.parametrize("seed", range(6))
-def test_randomized_mutation_parity(seed):
-    rng = Random(f"merkle-inc-{seed}")
+def test_randomized_mutation_parity(seed, kind):
+    make, mutate = KINDS[kind]
+    rng = Random(f"merkle-inc-{seed}" + ("" if kind == "zoo" else kind))
     incremental.enable()
-    z = incremental.track(random_zoo(rng))
+    z = incremental.track(make(rng))
     assert bytes(z.hash_tree_root()) == oracle(z)
     for step in range(40):
-        _mutate_once(rng, z)
+        mutate(rng, z)
         if rng.random() < 0.4:   # re-root mid-sequence, not only at the end
             assert bytes(z.hash_tree_root()) == oracle(z), (seed, step)
     assert bytes(z.hash_tree_root()) == oracle(z)
@@ -230,10 +346,12 @@ def test_diff_scaling_and_single_dispatch():
 # copy-on-write / txn discipline
 # ---------------------------------------------------------------------------
 
-def test_copy_shares_cache_copy_on_write():
+@kinds
+def test_copy_shares_cache_copy_on_write(kind):
+    make, mutate = KINDS[kind]
     incremental.enable()
     rng = Random("cow")
-    z = incremental.track(random_zoo(rng))
+    z = incremental.track(make(rng))
     base_root = bytes(z.hash_tree_root())
 
     c = z.copy()
@@ -243,25 +361,27 @@ def test_copy_shares_cache_copy_on_write():
 
     # mutate the COPY: base cache must stay intact (rollback semantics)
     for _ in range(10):
-        _mutate_once(rng, c)
+        mutate(rng, c)
     assert bytes(c.hash_tree_root()) == oracle(c)
     assert bytes(z.hash_tree_root()) == base_root == oracle(z)
 
     # mutate the BASE: the copy keeps its own root (commit semantics)
     copy_root = bytes(c.hash_tree_root())
     for _ in range(10):
-        _mutate_once(rng, z)
+        mutate(rng, z)
     assert bytes(z.hash_tree_root()) == oracle(z)
     assert bytes(c.hash_tree_root()) == copy_root == oracle(c)
 
 
-def test_txn_rollback_never_corrupts_base_cache():
+@kinds
+def test_txn_rollback_never_corrupts_base_cache(kind):
     """The txn/ overlay contract: handlers mutate a .copy() of the
     stored state; an abort drops the copy.  The base state's cached
     tree must answer the same root afterwards, with no rehash."""
+    make, mutate = KINDS[kind]
     incremental.enable()
     rng = Random("txn")
-    z = incremental.track(random_zoo(rng))
+    z = incremental.track(make(rng))
     base_root = bytes(z.hash_tree_root())
 
     class Abort(Exception):
@@ -270,7 +390,7 @@ def test_txn_rollback_never_corrupts_base_cache():
     try:
         txn_state = z.copy()
         for _ in range(8):
-            _mutate_once(rng, txn_state)
+            mutate(rng, txn_state)
         txn_state.hash_tree_root()     # mid-txn re-root, then crash
         raise Abort()
     except Abort:
@@ -282,22 +402,123 @@ def test_txn_rollback_never_corrupts_base_cache():
 
 
 # ---------------------------------------------------------------------------
+# flat elements: no cache of their own, no tracked objects beyond off + 1
+# ---------------------------------------------------------------------------
+
+def test_flat_elements_keep_no_cache():
+    incremental.enable()
+    rng = Random("flat-pin")
+    r = random_registry(rng, n=6)
+    r.pending.append(Pending())
+    incremental.track(r)
+    r.hash_tree_root()
+    for seq in (r.records, r.votes):
+        for i, e in enumerate(seq):
+            assert "_mcache" not in vars(e)
+            assert e._mc_seq is seq and e._mc_idx == i
+    # nested-mutable elements and flat fields keep their caches
+    assert all(incremental.is_tracked(p) for p in r.pending)
+    assert incremental.is_tracked(r.cp)
+    # a copy of a cached flat container entering a flat sequence drops
+    # the cache it brought and is linked like any element
+    r.cps.append(r.cp)
+    assert "_mcache" not in vars(r.cps[0]) and r.cps[0]._mc_seq is r.cps
+    # the copy's elements point at the copy's sequence
+    c = r.copy()
+    assert all(e._mc_seq is c.records and e._mc_idx == i
+               for i, e in enumerate(c.records))
+    assert all("_mcache" not in vars(e) for e in c.records)
+
+    # one field write re-plans one element's subtree (7 jobs) and the
+    # list's path, inside one sweep
+    r.hash_tree_root()
+    METRICS.reset()
+    r.records[3].exit = uint64(5)
+    assert bytes(r.hash_tree_root()) == oracle(r)
+    assert METRICS.count("merkle_flat_roots") == 1
+    assert METRICS.count("merkle_sweep_dispatches") == 1
+    assert METRICS.count("merkle_chunks_hashed") <= \
+        7 + incremental.type_tree_height(Registry)
+    # a middle pop re-indexes the later elements
+    r.records.pop(2)
+    assert [e._mc_idx for e in r.records] == list(range(len(r.records)))
+    r.records[4].active = uint64(1)
+    assert bytes(r.hash_tree_root()) == oracle(r)
+    # a replaced element is detached: writing it dirties nothing
+    old = r.records[0]
+    r.records[0] = random_record(rng)
+    assert old._mc_seq is None
+    root = bytes(r.hash_tree_root())
+    old.balance = uint64(1)
+    assert bytes(r.hash_tree_root()) == root == oracle(r)
+
+
+def _tracked_objects(roots, stop) -> int:
+    """GC-tracked objects reachable from `roots`, not through `stop`
+    nor through classes."""
+    seen = {id(stop)}
+    todo = list(roots)
+    n = 0
+    while todo:
+        o = todo.pop()
+        if id(o) in seen or isinstance(o, type) or not gc.is_tracked(o):
+            continue
+        seen.add(id(o))
+        n += 1
+        todo.extend(gc.get_referents(o))
+    return n
+
+
+def _copy_objects(view) -> int:
+    """Tracked objects a copy of `view` creates."""
+    gc.collect()
+    before = len(gc.get_objects())
+    c = view.copy()
+    gc.collect()
+    made = len(gc.get_objects()) - before
+    del c
+    return made
+
+
+def test_registry_object_count():
+    """A tracked registry element holds no more tracked objects than
+    with the mode off, plus one; a state copy makes no more per element
+    than off's copy, plus one."""
+    n = 2048
+    r = random_registry(Random("objects"), n=n)
+    elems = r.records._elems
+    off = _tracked_objects(elems, r.records) / n
+    off_copy = _copy_objects(r) / n
+    incremental.enable()
+    incremental.track(r)
+    r.hash_tree_root()
+    assert METRICS.count("merkle_flat_roots") >= n
+    on = _tracked_objects(elems, r.records) / n
+    on_copy = _copy_objects(r) / n
+    assert on <= off + 1, (on, off)
+    assert on_copy <= off_copy + 1, (on_copy, off_copy)
+
+
+# ---------------------------------------------------------------------------
 # resilience: faulted sweep site, breaker, guard
 # ---------------------------------------------------------------------------
 
-def _tracked_state_with_dirt(rng):
-    z = incremental.track(random_zoo(rng))
+def _tracked_state_with_dirt(rng, kind="zoo"):
+    make, mutate = KINDS[kind]
+    z = incremental.track(make(rng))
     z.hash_tree_root()
     for _ in range(5):
-        _mutate_once(rng, z)
+        mutate(rng, z)
     return z
 
 
-def test_sweep_site_raise_falls_back_to_full_rebuild():
+@kinds
+def test_sweep_site_raise_falls_back_to_full_rebuild(kind):
+    mutate = KINDS[kind][1]
     incremental.enable()
     resilience.enable(max_retries=0, breaker_threshold=1, probe_after=1000)
     rng = Random("fault-raise")
-    z = _tracked_state_with_dirt(rng)
+    z = _tracked_state_with_dirt(rng, kind)
     plan = FaultPlan([FaultSpec("ssz.merkle_sweep", "raise",
                                 persistent=True)], seed=7)
     with faults.inject(plan):
@@ -307,13 +528,13 @@ def test_sweep_site_raise_falls_back_to_full_rebuild():
         assert sup.breaker_state("ssz.merkle_sweep") == OPEN
         assert METRICS.count("merkle_full_rebuilds") >= 1
         # breaker open: further re-roots keep answering correctly
-        _mutate_once(rng, z)
+        mutate(rng, z)
         assert bytes(z.hash_tree_root()) == oracle(z)
     assert plan.total_fires() >= 1
     # dirty marks survived the degraded period: once the site heals,
     # the sweep resumes incrementally and stays byte-identical
     resilience.disable()
-    _mutate_once(rng, z)
+    mutate(rng, z)
     assert bytes(z.hash_tree_root()) == oracle(z)
 
 
@@ -357,11 +578,13 @@ def test_abandoned_sweep_never_writes_caches(monkeypatch):
     assert bytes(z.hash_tree_root()) == oracle(z)
 
 
-def test_sweep_corruption_caught_by_guard_and_quarantined():
+@kinds
+def test_sweep_corruption_caught_by_guard_and_quarantined(kind):
+    mutate = KINDS[kind][1]
     incremental.enable(guard_sample_rate=1.0, guard_seed=11)
     resilience.enable(max_retries=0, breaker_threshold=3)
     rng = Random("fault-corrupt")
-    z = _tracked_state_with_dirt(rng)
+    z = _tracked_state_with_dirt(rng, kind)
     plan = FaultPlan([FaultSpec("ssz.merkle_sweep", "corrupt",
                                 persistent=True)], seed=13)
     with faults.inject(plan):
@@ -376,7 +599,7 @@ def test_sweep_corruption_caught_by_guard_and_quarantined():
     # re-roots take the legacy full path (no further sweep dispatches)
     # and keep answering correctly
     dispatches = METRICS.count("merkle_sweep_dispatches")
-    _mutate_once(rng, z)
+    mutate(rng, z)
     assert bytes(z.hash_tree_root()) == oracle(z)
     assert METRICS.count("merkle_sweep_dispatches") == dispatches
     # a re-tracked state behind the quarantined site degrades to the
@@ -386,10 +609,11 @@ def test_sweep_corruption_caught_by_guard_and_quarantined():
     assert METRICS.count("merkle_full_rebuilds") >= 1
 
 
-def test_guard_passes_clean_sweeps():
+@kinds
+def test_guard_passes_clean_sweeps(kind):
     incremental.enable(guard_sample_rate=1.0, guard_seed=3)
     rng = Random("guard-clean")
-    z = _tracked_state_with_dirt(rng)
+    z = _tracked_state_with_dirt(rng, kind)
     assert bytes(z.hash_tree_root()) == oracle(z)
     assert METRICS.count("merkle_guard_samples") >= 1
     assert METRICS.count("merkle_guard_mismatches") == 0
