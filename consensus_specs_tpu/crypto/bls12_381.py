@@ -16,6 +16,7 @@ from . import curve as cv
 from .curve import Point, DecodeError
 from .pairing import pairing_check as _pairing_check, miller_loop, final_exponentiation
 from .hash_to_curve import hash_to_g2
+from ..utils.memo import Memo
 
 
 def _check_sk(sk: int) -> int:
@@ -35,21 +36,36 @@ def Sign(sk: int, message: bytes) -> bytes:
 
 def KeyValidate(pubkey: bytes) -> bool:
     try:
-        p = cv.g1_from_bytes(pubkey)
-    except DecodeError:
+        _load_pubkey(pubkey)
+    except ValueError:          # DecodeError or the infinity pubkey
         return False
-    return not p.is_infinity()
+    return True
 
 
-def _load_pubkey(pubkey: bytes) -> Point:
+# Validated decodes, keyed on the encoding: each distinct pubkey or
+# signature is decompressed and subgroup-checked once.  A decode that
+# raises stores nothing, so an invalid encoding is refused on every
+# call.  Points are never mutated in place (every operation returns a
+# new one), so callers may share them.
+_PUBKEYS = Memo()
+_SIGNATURES = Memo()
+
+
+def _decode_pubkey(pubkey: bytes) -> Point:
     p = cv.g1_from_bytes(pubkey)
     if p.is_infinity():
         raise ValueError("infinity pubkey")
     return p
 
 
+def _load_pubkey(pubkey: bytes) -> Point:
+    pubkey = bytes(pubkey)
+    return _PUBKEYS.get(pubkey, lambda: _decode_pubkey(pubkey))
+
+
 def _load_signature(signature: bytes) -> Point:
-    return cv.g2_from_bytes(signature)
+    signature = bytes(signature)
+    return _SIGNATURES.get(signature, lambda: cv.g2_from_bytes(signature))
 
 
 def Verify(pubkey: bytes, message: bytes, signature: bytes) -> bool:

@@ -21,7 +21,9 @@ BLS_X = -0xD201000000010000
 
 
 def fq_inv(a: int) -> int:
-    return pow(a, Q - 2, Q)
+    """a^-1 mod Q, and 0 for 0 (as a^(Q-2) would give)."""
+    a %= Q
+    return pow(a, -1, Q) if a else 0
 
 
 def fq_sqrt(a: int) -> int | None:

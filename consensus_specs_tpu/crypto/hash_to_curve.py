@@ -2,7 +2,8 @@
 
 RFC 9380 pipeline: expand_message_xmd(SHA-256) -> hash_to_field(Fq2, m=2,
 L=64) -> simplified SSWU onto the 3-isogenous curve E2' (A'=240u,
-B'=1012(1+u), Z=-(2+u)) -> 3-isogeny map to E2 -> clear cofactor by h_eff.
+B'=1012(1+u), Z=-(2+u)) -> 3-isogeny map to E2 -> clear cofactor by h_eff,
+computed with the psi endomorphism (RFC 9380 Appendix G.3).
 
 The isogeny-map coefficients are structurally verified at import: a wrong
 coefficient would send SSWU outputs (which provably lie on E2') off E2, and
@@ -14,7 +15,7 @@ from __future__ import annotations
 
 import hashlib
 
-from .fields import Q, Fq2
+from .fields import BLS_X, Q, XI, Fq2
 from .curve import Point, B2, g2_infinity
 
 DST_G2 = b"BLS_SIG_BLS12381G2_XMD:SHA-256_SSWU_RO_POP_"
@@ -24,7 +25,8 @@ _A = Fq2(0, 240)
 _B = Fq2(1012, 1012)
 _Z = Fq2(-2, -1)
 
-# effective cofactor for G2 cofactor clearing (h_eff)
+# effective cofactor for G2 cofactor clearing (h_eff): clear_cofactor(P)
+# is defined to equal [H_EFF]P
 H_EFF = 0xBC69F08F2EE75B3584C6A0EA91B352888E2A8E9145AD7689986FF031508FFE1329C2F178731DB956D82BF015D1212B02EC0EC69D7477C1AE954CBC06689F6A359894C0ADEBBF6B4E8020005AAA95551
 
 # 3-isogeny map coefficients (x_num, x_den, y_num, y_den), ascending powers
@@ -160,8 +162,27 @@ def iso_map(x: Fq2, y: Fq2) -> Point:
     return Point(xo, yo, Fq2.one(), B2)
 
 
+# psi = untwist-Frobenius-twist endomorphism of E2 (RFC 9380 Appendix
+# G.3): psi(x, y) = (PSI_X * conj(x), PSI_Y * conj(y))
+_PSI_X = XI.pow((Q - 1) // 3).inv()
+_PSI_Y = XI.pow((Q - 1) // 2).inv()
+
+
+def _psi(p: Point) -> Point:
+    # conjugation is a field automorphism, so it maps Jacobian Z as well
+    return Point(_PSI_X * p.x.conjugate(), _PSI_Y * p.y.conjugate(),
+                 p.z.conjugate(), B2)
+
+
 def clear_cofactor(p: Point) -> Point:
-    return p * H_EFF
+    """[H_EFF]P as [x^2 - x - 1]P + [x - 1]psi(P) + psi^2(2P), x = BLS_X
+    (Budroni-Pintore): two 64-bit multiplications in place of one
+    636-bit one."""
+    t1 = p * BLS_X
+    t2 = _psi(p)
+    t3 = _psi(_psi(p.double())) - t2
+    t2 = (t1 + t2) * BLS_X
+    return t3 + t2 - t1 - p
 
 
 def hash_to_g2(msg: bytes, dst: bytes = DST_G2) -> Point:

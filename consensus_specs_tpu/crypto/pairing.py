@@ -3,7 +3,8 @@
 From-scratch implementation over the fields.py tower.  G2 points are
 untwisted into E(Fq12) via (x, y) -> (x/w^2, y/w^3) (w^6 = XI, derived from
 the tower relations), and the Miller loop runs over the bits of |z| with
-line evaluations at the G1 argument.  The final exponentiation uses the
+line evaluations at the G1 argument (its point arithmetic stays on the
+twist, in Fq2).  The final exponentiation uses the
 easy (q^6-1)(q^2+1) step then the standard BLS12 x-chain hard part
 (cyclotomic squarings + Frobenius maps), computing e(P,Q)^3 uniformly —
 sound for every equality/is-one use (see final_exponentiation).
@@ -13,8 +14,8 @@ e(G1, [tau]G2) for the monomial points (tests/test_bls.py).
 """
 from __future__ import annotations
 
-from .fields import Q, R, BLS_X, Fq2, Fq6, Fq12
-from .curve import Point, Fq1
+from .fields import Q, R, BLS_X, XI, Fq2, Fq6, Fq12
+from .curve import Point
 
 # |z| bits for the Miller loop
 _ATE_LOOP = abs(BLS_X)
@@ -26,65 +27,35 @@ _ATE_LOOP = abs(BLS_X)
 _HARD_EXP = (Q**4 - Q * Q + 1) // R
 
 
-def _embed_fq2(a: Fq2) -> Fq12:
-    return Fq12(Fq6(a, Fq2.zero(), Fq2.zero()), Fq6.zero())
+# The untwist (x, y) -> (x w^-2, y w^-3) keeps T on the twist: a line's
+# slope through untwisted points is (twist slope) * w^-1, and
+# w^-1 = v^2 w / XI, w^-3 = v w / XI in the {1, v, v^2} x {1, w} basis.
+# The loop therefore runs in Fq2 with one Fq2 inversion a step and
+# assembles each line's Fq12 value from Fq2 parts; the values are the
+# Fq12-affine line evaluations exactly.
+_XI_INV = XI.inv()
+_Z2 = Fq2.zero()
 
 
-def _embed_fq(a: int) -> Fq12:
-    return Fq12(Fq6(Fq2(a, 0), Fq2.zero(), Fq2.zero()), Fq6.zero())
+def _slope(x1: Fq2, y1: Fq2, x2: Fq2, y2: Fq2) -> Fq2:
+    """Twist slope of the chord through two points, or of the tangent
+    when they are equal."""
+    if x1 == x2:
+        if y1 != y2:
+            raise ZeroDivisionError("vertical addition in miller loop")
+        num = x1.square()
+        return (num + num + num) * (y1 + y1).inv()
+    return (y2 - y1) * (x2 - x1).inv()
 
 
-# w   = (0, 1) in the Fq6 pair basis;  w^2 = v;  v^3 = XI
-_W = Fq12(Fq6.zero(), Fq6.one())
-_W2_INV = (_W * _W).inv()
-_W3_INV = (_W * _W * _W).inv()
-
-
-class _P12:
-    """Affine point over Fq12 (None coords = infinity)."""
-    __slots__ = ("x", "y")
-
-    def __init__(self, x: Fq12, y: Fq12):
-        self.x = x
-        self.y = y
-
-
-def _untwist(q: Point) -> _P12:
-    xa, ya = q.affine()
-    return _P12(_embed_fq2(xa) * _W2_INV, _embed_fq2(ya) * _W3_INV)
-
-
-def _line_eval(t: _P12, u: _P12, xp: Fq12, yp: Fq12) -> Fq12:
-    """Evaluate the line through T and U (or tangent at T if T==U) at P."""
-    if t.x == u.x and t.y == u.y:
-        # tangent: slope = 3x^2 / 2y
-        num = t.x.square()
-        num = num + num + num
-        den = t.y + t.y
-    elif t.x == u.x:
-        # vertical line
-        return xp - t.x
-    else:
-        num = u.y - t.y
-        den = u.x - t.x
-    slope = num * den.inv()
-    return slope * (xp - t.x) - (yp - t.y)
-
-
-def _p12_add(a: _P12, b: _P12) -> _P12:
-    if a.x == b.x and a.y == b.y:
-        num = a.x.square()
-        num = num + num + num
-        den = a.y + a.y
-    elif a.x == b.x:
-        raise ZeroDivisionError("vertical addition in miller loop")
-    else:
-        num = b.y - a.y
-        den = b.x - a.x
-    s = num * den.inv()
-    x3 = s.square() - a.x - b.x
-    y3 = s * (a.x - x3) - a.y
-    return _P12(x3, y3)
+def _step(f: Fq12, x: Fq2, y: Fq2, x2: Fq2, y2: Fq2, xp: int, yp: int):
+    """f * l_{T,U}(P) and T + U, for T = (x, y), U = (x2, y2) on the
+    twist: l = -yp + (s xp) w^-1 + (y - s x) w^-3 at P = (xp, yp)."""
+    s = _slope(x, y, x2, y2)
+    line = Fq12(Fq6(Fq2(-yp, 0), _Z2, _Z2),
+                Fq6(_Z2, (y - s * x) * _XI_INV, s * xp * _XI_INV))
+    x3 = s.square() - x - x2
+    return f * line, x3, s * (x - x3) - y
 
 
 def miller_loop(p: Point, q: Point) -> Fq12:
@@ -92,16 +63,14 @@ def miller_loop(p: Point, q: Point) -> Fq12:
     if p.is_infinity() or q.is_infinity():
         return Fq12.one()
     xa, ya = p.affine()
-    xp, yp = _embed_fq(xa.v), _embed_fq(ya.v)
-    qt = _untwist(q)
-    t = _P12(qt.x, qt.y)
+    xp, yp = xa.v, ya.v
+    xq, yq = q.affine()
+    x, y = xq, yq
     f = Fq12.one()
     for bit in bin(_ATE_LOOP)[3:]:
-        f = f.square() * _line_eval(t, t, xp, yp)
-        t = _p12_add(t, t)
+        f, x, y = _step(f.square(), x, y, x, y, xp, yp)
         if bit == "1":
-            f = f * _line_eval(t, qt, xp, yp)
-            t = _p12_add(t, qt)
+            f, x, y = _step(f, x, y, xq, yq, xp, yp)
     # z < 0: conjugate (differs from the true inverse by a norm-subfield
     # factor, which the final exponentiation kills)
     return f.conjugate()

@@ -79,7 +79,6 @@ from __future__ import annotations
 import hashlib
 
 from ..crypto import curve as cv
-from ..crypto.bls12_381 import _load_signature
 from ..crypto.curve import DecodeError
 from ..utils import bls
 from ..utils.spans import span
@@ -127,6 +126,12 @@ def _coefficients(entries):
             "little")
         out.append(1 + x % (2**64 - 1))
     return out
+
+
+def _load_signature(signature) -> cv.Point:
+    """A fresh decode and subgroup check, never the scalar oracle's memo:
+    a node meets each block's signatures for the first time."""
+    return cv.g2_from_bytes(bytes(signature))
 
 
 def _prepare(indices, sets, verdicts):

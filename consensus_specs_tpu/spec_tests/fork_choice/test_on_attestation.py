@@ -70,22 +70,6 @@ def test_on_attestation_rejects_current_slot(spec, state):
 @with_all_phases
 @spec_state_test
 @never_bls
-def test_on_attestation_rejects_unknown_block(spec, state):
-    store, steps, parts = start_fork_choice_test(spec, state)
-    for name, v in parts:
-        yield name, v
-    attestation = get_valid_attestation(spec, state, signed=True)
-    attestation.data.beacon_block_root = b"\x99" * 32
-    tick_to_slot(spec, store, int(state.slot) + 2, steps)
-    for name, v in add_attestation(spec, store, attestation, steps,
-                                   valid=False):
-        yield name, v
-    yield from emit_steps(steps)
-
-
-@with_all_phases
-@spec_state_test
-@never_bls
 def test_proposer_boost_set_and_reset(spec, state):
     """A timely first block sets proposer_boost_root; the next slot
     tick clears it (fork-choice.md proposer-boost lifecycle)."""

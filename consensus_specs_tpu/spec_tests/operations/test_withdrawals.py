@@ -176,19 +176,6 @@ def test_withdrawable_epoch_but_0_effective_balance_not_0_balance(
 
 @with_all_phases_from("capella")
 @spec_state_test
-def test_no_withdrawals_but_some_next_epoch(spec, state):
-    """Withdrawability starting next epoch: nothing withdrawable yet."""
-    index = 0
-    prepare_fully_withdrawable_validator(spec, state, index)
-    state.validators[index].withdrawable_epoch = uint64(
-        int(spec.get_current_epoch(state)) + 1)
-    payload = payload_with_expected_withdrawals(spec, state)
-    assert len(payload.withdrawals) == 0
-    yield from run_withdrawals_processing(spec, state, payload)
-
-
-@with_all_phases_from("capella")
-@spec_state_test
 def test_partially_withdrawable_exact_max_balance(spec, state):
     """Balance exactly AT the max effective balance: NOT partially
     withdrawable (strict inequality)."""
@@ -229,19 +216,6 @@ def test_invalid_withdrawal_index_gap(spec, state):
     assert len(payload.withdrawals) > 0
     payload.withdrawals[0].index = uint64(
         int(payload.withdrawals[0].index) + 1)
-    yield from run_withdrawals_processing(spec, state, payload,
-                                          valid=False)
-
-
-@with_all_phases_from("capella")
-@spec_state_test
-def test_invalid_extra_withdrawal(spec, state):
-    prepare_fully_withdrawable_validator(spec, state, 0)
-    payload = payload_with_expected_withdrawals(spec, state)
-    extra = payload.withdrawals[0].copy()
-    extra.index = uint64(int(extra.index) + 1)
-    extra.validator_index = uint64(1)
-    payload.withdrawals = list(payload.withdrawals) + [extra]
     yield from run_withdrawals_processing(spec, state, payload,
                                           valid=False)
 
@@ -522,7 +496,7 @@ def test_invalid_max_per_slot_full_withdrawals_and_one_less_in_withdrawals(
 
 @with_all_phases_from("capella")
 @spec_state_test
-def test_withdrawable_epoch_but_0_balance(spec, state):
+def test_withdrawable_epoch_but_0_effective_balance_0_balance(spec, state):
     prepare_fully_withdrawable_validator(spec, state, 0, balance=0)
     state.validators[0].effective_balance = 0
     payload = payload_with_expected_withdrawals(spec, state)

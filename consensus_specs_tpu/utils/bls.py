@@ -17,6 +17,8 @@ from __future__ import annotations
 
 import functools
 
+from .memo import Memo
+
 # global switches (reference: bls.py:74-124)
 bls_active = True
 _backend_name = "native"
@@ -86,34 +88,10 @@ def _dispatch(site, device_fn, fallback_fn):
 # supervised fallback of every device dispatch (byte-identical semantics:
 # backend import errors surface, DecodeError/ValueError reads as invalid).
 
-class _Memo:
-    """Bounded FIFO memo over PURE primitives (sign and the scalar
-    verify oracles are functions of their byte inputs, nothing else).
-    The test tier rebuilds identical blocks from the cached genesis
-    state file after file, re-deriving byte-identical signatures and
-    verdicts hundreds of times at ~100 ms a pairing; the memo sits
-    BELOW the dispatch seam, so fault injection, supervision, and the
-    differential guard still fire on every call."""
-
-    _MISS = object()
-
-    def __init__(self, cap: int = 1 << 14):
-        self._store: dict = {}
-        self._cap = cap
-
-    def get(self, key, compute):
-        hit = self._store.get(key, self._MISS)
-        if hit is not self._MISS:
-            return hit
-        value = compute()
-        if len(self._store) >= self._cap:
-            self._store.pop(next(iter(self._store)))
-        self._store[key] = value
-        return value
-
-
-_SIGN_MEMO = _Memo()
-_VERIFY_MEMO = _Memo()
+# memos BELOW the dispatch seam: fault injection, supervision and the
+# differential guard still fire on every call
+_SIGN_MEMO = Memo()
+_VERIFY_MEMO = Memo()
 
 
 def _native_verify(PK, message, signature):

@@ -4,6 +4,7 @@ Tests run on CPU with 8 virtual XLA devices so multi-chip sharding
 (jax.sharding.Mesh + shard_map) is exercised without TPU hardware.  These
 env vars must be set before jax initializes, hence at conftest import time.
 """
+import gc
 import os
 
 os.environ["JAX_PLATFORMS"] = "cpu"
@@ -85,6 +86,15 @@ def pytest_configure(config):
         "markers",
         "slow: multi-minute pure-python crypto workload (mainnet-size "
         "whisk proofs); runs under --kernel-tiers / RUN_KERNEL_TIERS=1")
+
+
+def pytest_unconfigure(config):
+    # After the summary, the session still holds about a million objects
+    # (cached genesis states, specs, decoded points).  The interpreter's
+    # exit would sweep them in full collections for seconds before the
+    # OS takes the memory back; frozen, they are skipped.  Atexit
+    # handlers and the flush of the standard streams still run.
+    gc.freeze()
 
 
 def pytest_collection_modifyitems(config, items):

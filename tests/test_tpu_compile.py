@@ -12,7 +12,8 @@ read back without one).
 Tier-1 holds the programs that compile in seconds to tens of seconds;
 the limb-arithmetic programs whose TPU compile takes half a minute to
 minutes (Miller steps, final-exponentiation stages, Pippenger, the G2
-ladders) are marked slow.
+ladders) and the SHA-256 Pallas kernel at its full tile are marked
+slow, the kernel with a narrow-tile sibling in tier-1.
 """
 import os
 
@@ -74,9 +75,14 @@ def _epoch_statics():
             ("max_eb", 32000000000), ("target_flag", 1), ("wd", 64))
 
 
-def test_sha256_pallas_kernel(one_chip):
+@pytest.mark.parametrize("lanes", [
+    8, pytest.param(sha256_pallas.LANES, marks=pytest.mark.slow)])
+def test_sha256_pallas_kernel(one_chip, lanes):
+    """The kernel's compile time grows with its tile: the chip's 256-row
+    tile takes half a minute, an 8-row tile of the same rounds about a
+    second."""
     c = _compile(sha256_pallas._hash_pairs_pallas_fixed,
-                 _spec(one_chip, (2 * 65536, 8)), lanes=256)
+                 _spec(one_chip, (2 * 65536, 8)), lanes=lanes)
     assert "tpu_custom_call" in c.as_text()
 
 
